@@ -65,6 +65,7 @@ _EXPORTS = {
     "EagerEngine": ".engines",
     "compare_engines": ".engines",
     "COLLECTIVE_OPS": ".hlo_tree",
+    "DeviceTree": ".hlo_tree",
     "build_device_tree": ".hlo_tree",
     "collective_summary": ".hlo_tree",
     "load_device_tree": ".hlo_tree",
@@ -77,8 +78,9 @@ _EXPORTS = {
     "annotate_tree": ".planes",
     "dominant_term": ".planes",
     "select_plane": ".planes",
-    "V5E": ".roofline",
+    "PEAKS": ".roofline",
     "HardwareSpec": ".roofline",
+    "peaks_for": ".roofline",
     "RooflineReport": ".roofline",
     "report_from_artifacts": ".roofline",
 }

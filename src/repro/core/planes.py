@@ -32,12 +32,17 @@ Annotation metric keys written onto merged-plane nodes:
 * ``hlo_flops`` / ``hlo_bytes`` / ``hlo_coll_bytes`` / ``hlo_ops`` — the HLO
   subtree cost attributed to that host node;
 * ``rt_compute`` / ``rt_memory`` / ``rt_collective`` — the three roofline
-  term times (seconds) those costs imply on the hardware spec;
+  term times (seconds) those costs imply on the peaks of the device tree's
+  ``device_kind`` (``core/roofline.PEAKS``);
 * ``roofline_occupancy`` — the node's bound time (max of its three terms) as
   a fraction of the root's roofline step time: "this component accounts for
   X% of the step's roofline bound" (root = 1.0);
 * ``dominant::compute|memory|collective`` — exactly one per annotated node,
   valued at the bound time in seconds (the flamegraph's coloring key).
+
+A device tree whose ``device_kind`` has no entry in the peaks table (a CPU
+run, or an artifact that does not say) gets the ``hlo_*`` counters only: no
+roofline term, occupancy or dominant key.  :func:`roofline_note` says why.
 
 Pure stdlib + :mod:`repro.core.calltree` / :mod:`repro.core.roofline` — no
 jax import, so the merge layer is usable by the daemon/server hot paths and
@@ -50,7 +55,8 @@ import weakref
 from collections.abc import Mapping
 
 from .calltree import CallNode, CallTree
-from .roofline import V5E, HardwareSpec
+from .hlo_tree import DeviceTree
+from .roofline import PEAKS, peaks_for
 
 PLANES = ("host", "device", "merged", "static")
 
@@ -129,6 +135,16 @@ _INDEX_CACHE: "weakref.WeakKeyDictionary[CallTree, dict[str, tuple[float, float,
 _HLO_FULL_KEYS = tuple(HLO_PREFIX + k for k in HLO_KEYS)
 
 
+def roofline_note(device: DeviceTree) -> str | None:
+    """Why the merged plane over ``device`` carries no roofline terms; None when it does."""
+    if peaks_for(device.device_kind) is not None:
+        return None
+    return (
+        f"no roofline terms: device kind {device.device_kind!r} has no entry in the peaks table "
+        f"(known: {', '.join(sorted(PEAKS))})"
+    )
+
+
 def _device_index(device: CallTree) -> dict[str, tuple[float, float, float, float]]:
     """Flatten-view index: normalized name -> (flops, bytes, coll_bytes, ops)."""
     index = _INDEX_CACHE.get(device)
@@ -162,9 +178,7 @@ def device_name_index(device: CallTree) -> dict[str, dict[str, float]]:
 _NORM_CACHE: dict[str, str] = {}
 
 
-def annotate_tree(
-    host: CallTree, device: CallTree, hw: HardwareSpec = V5E, *, copy: bool = True
-) -> CallTree:
+def annotate_tree(host: CallTree, device: DeviceTree, *, copy: bool = True) -> CallTree:
     """The merged plane: ``host`` with device-plane annotations.
 
     Annotations keep inclusive-metric semantics: a matched node carries its
@@ -191,9 +205,11 @@ def annotate_tree(
     """
     merged = host.copy() if copy else host
     index = _device_index(device)
-    inv_c = 1.0 / hw.peak_flops
-    inv_m = 1.0 / hw.hbm_bw
-    inv_x = 1.0 / (hw.ici_links * hw.ici_link_bw)
+    hw = peaks_for(device.device_kind)
+    # No peaks: every term is zero, so no node gets a bound or an occupancy.
+    inv_c = 1.0 / hw.peak_flops if hw else 0.0
+    inv_m = 1.0 / hw.hbm_bw if hw else 0.0
+    inv_x = 1.0 / (hw.ici_links * hw.ici_link_bw) if hw else 0.0
     k_flops, k_bytes, k_coll, k_ops = _HLO_FULL_KEYS
     rt_c, rt_m, rt_x = (TERM_PREFIX + t for t in ROOFLINE_TERMS)
     dom = tuple(DOMINANT_PREFIX + t for t in ROOFLINE_TERMS)
@@ -289,10 +305,9 @@ def dominant_term(metrics: Mapping[str, float]) -> str | None:
 
 def select_plane(
     host: CallTree,
-    device: CallTree | None,
+    device: DeviceTree | None,
     plane: str,
     *,
-    hw: HardwareSpec = V5E,
     profile: str | None = None,
     static: CallTree | None = None,
 ) -> CallTree:
@@ -315,4 +330,4 @@ def select_plane(
         raise PlaneError(missing_device_hint(profile))
     if plane == "device":
         return device
-    return annotate_tree(host, device, hw)
+    return annotate_tree(host, device)

@@ -21,17 +21,32 @@ from .hlo_tree import COLLECTIVE_OPS
 
 @dataclass(frozen=True)
 class HardwareSpec:
-    """TPU v5e-class chip (task-specified constants)."""
+    """Published peaks of one chip."""
 
-    name: str = "tpu-v5e"
-    peak_flops: float = 197e12  # bf16 FLOP/s per chip
-    hbm_bw: float = 819e9  # bytes/s per chip
-    ici_link_bw: float = 50e9  # bytes/s per link
-    ici_links: int = 4  # links used by a chip in a 2D torus (2 axes x 2 dirs)
-    hbm_bytes: float = 16e9  # capacity, for fit checks
+    name: str
+    peak_flops: float  # bf16 FLOP/s per chip
+    hbm_bw: float  # bytes/s per chip
+    ici_link_bw: float  # bytes/s per link
+    ici_links: int  # links used by a chip in a 2D torus (2 axes x 2 dirs)
+    hbm_bytes: float  # capacity, for fit checks
 
 
-V5E = HardwareSpec()
+V5E_KIND = "TPU v5 lite"
+
+#: Peaks keyed by ``jax.Device.device_kind``.  A kind with no entry has no
+#: roofline: it is never costed with another chip's peaks.
+PEAKS: dict[str, HardwareSpec] = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM
+    # at 819 GB/s, 1,600 Gbit/s of inter-chip interconnect (4 x 50 GB/s).
+    V5E_KIND: HardwareSpec(
+        name="tpu-v5e", peak_flops=197e12, hbm_bw=819e9, ici_link_bw=50e9, ici_links=4, hbm_bytes=16e9
+    ),
+}
+
+
+def peaks_for(device_kind: str | None) -> HardwareSpec | None:
+    """The peaks table entry for a ``device_kind``, or None when it has none."""
+    return PEAKS.get(device_kind) if device_kind else None
 
 
 @dataclass
@@ -43,10 +58,10 @@ class RooflineReport:
     flops_per_device: float
     bytes_per_device: float
     coll_bytes_per_device: float
+    hw: HardwareSpec
     coll_by_kind: dict[str, float] = field(default_factory=dict)
     model_flops_global: float = 0.0  # 6*N*D (dense) or 6*N_active*D (MoE)
     per_device_hbm_peak: float = 0.0  # from memory_analysis
-    hw: HardwareSpec = V5E
 
     @property
     def t_compute(self) -> float:
@@ -128,8 +143,8 @@ def report_from_artifacts(
     cost_analysis: dict,
     device_tree: CallTree,
     memory_analysis=None,
+    hw: HardwareSpec,
     model_flops_global: float = 0.0,
-    hw: HardwareSpec = V5E,
 ) -> RooflineReport:
     # XLA's cost_analysis() counts while-loop bodies ONCE (verified: its FLOPs
     # fall short of 6ND by ~the layer count for scanned stacks). The device

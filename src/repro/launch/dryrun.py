@@ -32,7 +32,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.configs import SHAPES, get_config, list_archs, shape_applicable  # noqa: E402
 from repro.core.hlo_tree import build_device_tree, collective_summary, save_device_tree  # noqa: E402
-from repro.core.roofline import report_from_artifacts  # noqa: E402
+from repro.core.roofline import PEAKS, V5E_KIND, report_from_artifacts  # noqa: E402
 from repro.launch.mesh import make_production_mesh, mesh_chips  # noqa: E402
 from repro.launch.steps import make_serve_step, make_train_step  # noqa: E402
 from repro.models import Model  # noqa: E402
@@ -188,7 +188,9 @@ def run_cell(
         ca = compiled.cost_analysis() or {}
         if isinstance(ca, (list, tuple)):  # older jax returns [per-device dict]
             ca = ca[0] if ca else {}
-        tree = build_device_tree(compiled.as_text(), step_name=f"{arch}:{shape_name}")
+        # The production meshes are v5e pods: the artifact is costed for, and
+        # says, that kind (the program itself was compiled for CPU devices).
+        tree = build_device_tree(compiled.as_text(), step_name=f"{arch}:{shape_name}", device_kind=V5E_KIND)
         colls = collective_summary(tree)
         if dump_tree:
             os.makedirs(os.path.dirname(dump_tree) or ".", exist_ok=True)
@@ -207,11 +209,13 @@ def run_cell(
             cost_analysis=ca,
             device_tree=tree,
             memory_analysis=ma,
+            hw=PEAKS[V5E_KIND],
             model_flops_global=model.model_flops(shape),
         )
         cell.update(
             status="ok",
             chips=chips,
+            device_kind=V5E_KIND,
             lower_s=round(t_lower, 1),
             compile_s=round(t_compile, 1),
             memory_analysis={

@@ -8,21 +8,14 @@ importing this module never touches jax device state — the dry-run must set
 from __future__ import annotations
 
 import jax
-
-
-def axis_types_kw(n: int) -> dict:
-    """``axis_types=`` kwarg when this jax has explicit axis types (>=0.5);
-    older versions (0.4.x) predate ``jax.sharding.AxisType`` and default to
-    auto sharding anyway."""
-    at = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (at.Auto,) * n} if at is not None else {}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2x16x16 = 512 chips across two pods."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **axis_types_kw(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model_axis: int = 1):
@@ -33,7 +26,7 @@ def make_host_mesh(model_axis: int = 1):
     return jax.make_mesh(
         (n // model_axis, model_axis),
         ("data", "model"),
-        **axis_types_kw(2),
+        axis_types=(AxisType.Auto,) * 2,
     )
 
 

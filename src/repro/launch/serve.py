@@ -31,6 +31,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core import DominanceDetector, Rule, SamplerConfig, WatchdogLoop, make_sampler
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.steps import make_serve_step
 from repro.models import Model
 
@@ -168,6 +169,7 @@ def main():
                     help="node name reported to the aggregator (default: hostname)")
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_config(args.arch, smoke=not args.full)
     model = Model(cfg)
     rng = np.random.default_rng(0)

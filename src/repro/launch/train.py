@@ -39,6 +39,7 @@ from repro.core import (
     write_report,
 )
 from repro.data import DataConfig, Pipeline, SyntheticLM
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.steps import make_train_step
 from repro.models import Model
 from repro.optim import AdamWConfig, adamw_init, cosine_schedule
@@ -133,7 +134,7 @@ class Trainer:
         self.detector.add_callback(self._on_anomaly)
         self.watchdog = WatchdogLoop(self.sampler, self.detector, interval_s=1.0) if self.sampler else None
         self.anomalies: list = []
-        self._device_tree_dumped = not job.profile  # device plane rides the profiling plane
+        self._step_exe = None  # compiled on the first step, from its batch
 
     # -- fault-tolerance hooks ---------------------------------------------------
 
@@ -146,32 +147,30 @@ class Trainer:
         with open(self._heartbeat_path, "w") as f:
             f.write(f"{self.step} {time.time()}")
 
-    def _dump_device_tree(self, batch: dict) -> None:
-        """Drop the device-plane artifact beside the host profile (once).
+    def _compile_step(self, batch: dict) -> None:
+        """AOT-compile the train step for this run's shapes; the loop calls it.
 
-        AOT lower+compile of the same train step the loop runs, costed into a
-        CallTree by ``op_name`` path — the daemon/server merge it onto the
-        sampled host tree (``?plane=merged``).  Also lands in the launcher's
-        per-target daemon dir (``REPRO_PROFILERD_OUT``) where the shared
-        daemon's lazy discovery picks it up.  Best-effort: the device plane
-        must never cost the training run.
+        With profiling on, the compiled program is also the device plane:
+        costed into a CallTree by ``op_name`` path and dropped beside the
+        host profile, where the daemon/server merge it onto the sampled host
+        tree (``?plane=merged``).  It also lands in the launcher's per-target
+        daemon dir (``REPRO_PROFILERD_OUT``) where the shared daemon's lazy
+        discovery picks it up.  A failure here fails the run.
         """
-        self._device_tree_dumped = True
-        try:
-            from repro.core.hlo_tree import save_device_tree, tree_from_compiled
+        self._step_exe = self._train_step.lower(self.params, self.opt_state, batch).compile()
+        if not self.job.profile:
+            return
+        from repro.core.hlo_tree import save_device_tree, tree_from_compiled
 
-            compiled = self._train_step.lower(self.params, self.opt_state, batch).compile()
-            tree = tree_from_compiled(compiled)
-            dests = [os.path.join(self.job.out_dir, "device_tree.json")]
-            env_out = os.environ.get("REPRO_PROFILERD_OUT")
-            if env_out:
-                dests.append(os.path.join(env_out, "device_tree.json"))
-            for p in dests:
-                os.makedirs(os.path.dirname(p) or ".", exist_ok=True)
-                save_device_tree(tree, p, meta={"arch": self.cfg.name, "source": "train"})
-            print(f"[train] device plane: {dests[0]} ({tree.node_count()} call sites)")
-        except Exception as e:  # noqa: BLE001 - any failure here is non-fatal
-            print(f"[train] device-tree dump skipped: {e}")
+        tree = tree_from_compiled(self._step_exe, device_kind=jax.devices()[0].device_kind)
+        dests = [os.path.join(self.job.out_dir, "device_tree.json")]
+        env_out = os.environ.get("REPRO_PROFILERD_OUT")
+        if env_out:
+            dests.append(os.path.join(env_out, "device_tree.json"))
+        for p in dests:
+            os.makedirs(os.path.dirname(p) or ".", exist_ok=True)
+            save_device_tree(tree, p, meta={"arch": self.cfg.name, "source": "train"})
+        print(f"[train] device plane: {dests[0]} ({tree.node_count()} call sites, {tree.device_kind})")
 
     def _state_tree(self) -> dict:
         return {
@@ -207,11 +206,11 @@ class Trainer:
         try:
             while self.step < self.job.steps:
                 batch = {k: jnp.asarray(v) for k, v in next(self.data).items()}
-                if not self._device_tree_dumped:
+                if self._step_exe is None:
                     # Before the step call: donation invalidates the argument
                     # buffers, and lowering only needs their avals anyway.
-                    self._dump_device_tree(batch)
-                self.params, self.opt_state, metrics = self._train_step(
+                    self._compile_step(batch)
+                self.params, self.opt_state, metrics = self._step_exe(
                     self.params, self.opt_state, batch
                 )
                 self.step += 1
@@ -242,9 +241,11 @@ class Trainer:
         }
         with open(os.path.join(self.job.out_dir, "metrics.json"), "w") as f:
             json.dump({"summary": summary, "steps": self.metrics_log}, f, indent=1)
-        if host_tree is not None and host_tree.total() > 0:
-            write_report(host_tree, self.job.out_dir, "host_profile")
-            summary["host_profile"] = os.path.join(self.job.out_dir, "host_profile.html")
+        if host_tree is not None:
+            summary["profile_samples"] = host_tree.total()
+            if host_tree.total() > 0:
+                write_report(host_tree, self.job.out_dir, "host_profile")
+                summary["host_profile"] = os.path.join(self.job.out_dir, "host_profile.html")
         return summary
 
 
@@ -284,6 +285,7 @@ def main():
         push_url=args.push,
         push_node=args.push_node,
     )
+    use_compile_cache()
     summary = Trainer(job).run()
     print(json.dumps(summary, indent=1))
 

@@ -97,7 +97,10 @@ def mlstm(params, x, cfg, *, state=None, scope: str = "mlstm"):
                 # w_ij = exp(cumf_i - cumf_j + li_j) for j <= i
                 Eij = cumf[:, :, None] - cumf[:, None, :] + li[:, None, :]  # (B,L,L,H)
                 mask = jnp.tril(jnp.ones((L, L), bool))
-                w = jnp.where(mask[None, :, :, None], jnp.exp(Eij), 0.0)
+                # Mask before exp: above the diagonal Eij grows with the
+                # chunk and overflows, and the inf would turn the masked-out
+                # gradient (0 * inf) into NaN.
+                w = jnp.exp(jnp.where(mask[None, :, :, None], Eij, -jnp.inf))
                 s = jnp.einsum("blhk,bmhk->blmh", qb, kb) * w
                 num_intra = jnp.einsum("blmh,bmhk->blhk", s, vb)
                 den_vec = jnp.einsum("blmh,bmhk->blhk", w, kb)

@@ -56,7 +56,7 @@ import os
 import sys
 
 from repro.core.detector import Rule, TrendRule
-from repro.core.planes import PLANES, PlaneError, default_metric, select_plane
+from repro.core.planes import PLANES, PlaneError, default_metric, roofline_note, select_plane
 
 from .daemon import DaemonConfig, ProfilerDaemon, rule_from_spec
 from .profiles import (
@@ -85,7 +85,10 @@ def _resolve_plane(tree, profile_path: str, plane: str):
         return select_plane(
             tree, None, plane, profile=profile_path, static=load_static_plane(profile_path)
         )
-    return select_plane(tree, load_device_plane(profile_path), plane, profile=profile_path)
+    device = load_device_plane(profile_path)
+    if plane == "merged" and device is not None and (note := roofline_note(device)):
+        print(f"[profilerd] {profile_path}: {note}", file=sys.stderr)
+    return select_plane(tree, device, plane, profile=profile_path)
 
 
 def _print_status(d: ProfilerDaemon) -> None:

@@ -141,7 +141,9 @@ def spawn_attached_daemon(
     src_root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     env = dict(os.environ)
     env["PYTHONPATH"] = src_root + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    # The daemon never imports JAX; pinning it to the CPU outright keeps a
+    # stray import from ever claiming the accelerator its target holds.
+    env["JAX_PLATFORMS"] = "cpu"
     cmd = [sys.executable, "-m", "repro.profilerd", "attach"]
     if spool_path is not None:
         cmd += ["--spool", spool_path]
@@ -653,8 +655,11 @@ class ProfilerDaemon:
                 pass  # serving still works from the in-memory tree
         if self.shared is not None:
             self.shared.set_device_tree(tree)
+        from repro.core.planes import roofline_note
+
         self._record_event(
-            {"kind": "DEVICE_TREE_LOADED", "path": path,
+            {"kind": "DEVICE_TREE_LOADED", "path": path, "device_kind": tree.device_kind,
+             "no_roofline": roofline_note(tree),
              "call_sites": tree.node_count(), "wall_time": time.time()}
         )
 

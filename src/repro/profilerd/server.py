@@ -48,12 +48,14 @@ from repro.core.export import (
     export_tree,
     prepare_view,
 )
+from repro.core.hlo_tree import DeviceTree
 from repro.core.planes import (
     OCCUPANCY,
     PLANES,
     PlaneError,
     default_metric,
     dominant_term,
+    roofline_note,
     select_plane,
 )
 from repro.core.report import ViewConfig, render_diff
@@ -555,6 +557,10 @@ class _Handler(BaseHTTPRequestHandler):
             raise _HTTPError(400, f"unknown plane {plane!r}; choose from {', '.join(PLANES)}")
         return plane
 
+    def _device_tree(self, target: str | None) -> DeviceTree | None:
+        getter = getattr(self.server.source, "device_tree", None)
+        return getter(target) if getter is not None else None
+
     def _plane_tree(self, tree: CallTree, plane: str, target: str | None) -> CallTree:
         """Resolve the requested plane over a host tree from our source.
 
@@ -570,8 +576,7 @@ class _Handler(BaseHTTPRequestHandler):
             getter = getattr(source, "static_tree", None)
             static = getter(target) if getter is not None else None
         else:
-            getter = getattr(source, "device_tree", None)
-            device = getter(target) if getter is not None else None
+            device = self._device_tree(target)
         try:
             return select_plane(
                 tree, device, plane, profile=getattr(source, "path", None), static=static
@@ -595,6 +600,9 @@ class _Handler(BaseHTTPRequestHandler):
             label = f"{label} [{target}]"
         if plane != "host":
             label = f"{label} [{plane} plane]"
+        device = self._device_tree(target) if plane == "merged" else None
+        if device is not None and (note := roofline_note(device)):
+            label = f"{label} [{note}]"
         if fmt == "csv":
             # The CSV body carries its own marker rows; serve it as-is.
             return export_tree(tree, "csv", view=view, metric=metric, title=label), CONTENT_TYPES["csv"]

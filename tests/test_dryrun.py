@@ -13,7 +13,7 @@ import sys
 
 import pytest
 
-from repro.launch.mesh import axis_types_kw
+from jax.sharding import AxisType
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ENV = {**os.environ, "PYTHONPATH": os.path.join(REPO, "src")}
@@ -71,7 +71,7 @@ def test_batch_shardings_shard_batch_dim_only():
         shape = {"data": 2, "model": 1}
 
     # real 1-device mesh for NamedSharding construction
-    mesh = jax.make_mesh((1, 1), ("data", "model"), **axis_types_kw(2))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
     batch = {"tokens": jax.ShapeDtypeStruct((8, 16), jnp.int32)}
     sh = batch_shardings(batch, mesh, ("data",))
     assert sh["tokens"].spec[0] in ("data", ("data",))
@@ -83,7 +83,7 @@ def test_state_shardings_prefer_head_axis():
 
     from repro.launch.dryrun import state_shardings
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"), **axis_types_kw(2))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
     state = {"scan": {"block0": {"k": jax.ShapeDtypeStruct((12, 4, 128, 16, 64), jnp.bfloat16)}}}
     sh = state_shardings(state, mesh, ("data",))
     spec = sh["scan"]["block0"]["k"].spec

@@ -6,8 +6,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.launch.mesh import axis_types_kw
-
 from repro.core import (
     build_device_tree,
     collective_summary,
@@ -65,6 +63,74 @@ ENTRY %main (a: f32[8]) -> f32[8] {
         assert w.opcode == "while"
         assert w.trip_count == 12
         assert "body" in w.called and "cond" in w.called
+
+    def test_tpu_loop_and_fused_convolution(self):
+        """TPU HLO: tiled layouts, a scan whose trip count is only in its
+        condition, and a matmul lowered to a convolution inside a fusion."""
+        text = """HloModule tpu
+%fused_mm (param_0: bf16[8,768], param_1: bf16[768,256]) -> bf16[8,256] {
+  %param_0 = bf16[8,768]{1,0:T(8,128)(2,1)} parameter(0)
+  %param_1 = bf16[768,256]{1,0:T(8,128)(2,1)} parameter(1)
+  ROOT %convolution.1 = bf16[8,256]{1,0:T(8,128)(2,1)} convolution(%param_0, %param_1), dim_labels=bf_io->bf, metadata={op_name="jit(step)/layers/while/body/mlp/dot_general"}
+}
+%body (arg: (s32[], bf16[8,768], bf16[768,256])) -> (s32[], bf16[8,768], bf16[768,256]) {
+  %arg = (s32[]{:T(128)}, bf16[8,768]{1,0:T(8,128)(2,1)S(1)}, bf16[768,256]{1,0:T(8,128)(2,1)}) parameter(0)
+  %i = s32[]{:T(128)} get-tuple-element(%arg), index=0
+  %x = bf16[8,768]{1,0:T(8,128)(2,1)S(1)} get-tuple-element(%arg), index=1
+  %w = bf16[768,256]{1,0:T(8,128)(2,1)} get-tuple-element(%arg), index=2
+  %fusion.1 = bf16[8,256]{1,0:T(8,128)(2,1)} fusion(%x, %w), kind=kOutput, calls=%fused_mm
+  ROOT %t = (s32[]{:T(128)}, bf16[8,768]{1,0:T(8,128)(2,1)S(1)}, bf16[768,256]{1,0:T(8,128)(2,1)}) tuple(%i, %x, %w)
+}
+%cond (arg.1: (s32[], bf16[8,768], bf16[768,256])) -> pred[] {
+  %constant.3 = s32[]{:T(128)} constant(3)
+  %arg.1 = (s32[]{:T(128)}, bf16[8,768]{1,0:T(8,128)(2,1)S(1)}, bf16[768,256]{1,0:T(8,128)(2,1)}) parameter(0)
+  %gte = s32[]{:T(128)} get-tuple-element(%arg.1), index=0
+  ROOT %lt.1 = pred[]{:T(512)} compare(%gte, %constant.3), direction=LT, metadata={op_name="jit(step)/layers/while/cond/lt"}
+}
+ENTRY %main (x: bf16[8,768], w: bf16[768,256]) -> bf16[8,768] {
+  %x = bf16[8,768]{1,0:T(8,128)(2,1)} parameter(0)
+  %w = bf16[768,256]{1,0:T(8,128)(2,1)} parameter(1)
+  %zero = s32[]{:T(128)} constant(0)
+  %init = (s32[]{:T(128)}, bf16[8,768]{1,0:T(8,128)(2,1)S(1)}, bf16[768,256]{1,0:T(8,128)(2,1)}) tuple(%zero, %x, %w)
+  %loop = (s32[]{:T(128)}, bf16[8,768]{1,0:T(8,128)(2,1)S(1)}, bf16[768,256]{1,0:T(8,128)(2,1)}) while(%init), condition=%cond, body=%body
+  ROOT %out = bf16[8,768]{1,0:T(8,128)(2,1)} get-tuple-element(%loop), index=1
+}
+"""
+        comps = parse_hlo_module(text)
+        assert comps["main"].ops["loop"].trip_count == 3
+        assert comps["main"].ops["init"].shapes[1] == ("bf16", (8, 768))
+        tree = build_device_tree(text)
+        assert tree.total("flops") == 3 * 2 * 8 * 768 * 256
+        # The flops sit at the inner convolution's own op_name, not the fusion's.
+        leaves = [(tuple(p), n.metrics["flops"]) for p, n in tree.root.walk() if n.metrics.get("flops") and not n.children]
+        assert len(leaves) == 1
+        path, flops = leaves[0]
+        assert flops == tree.total("flops")
+        assert "mlp" in path and "fusion" not in path
+
+    def test_fusion_moves_its_slices_not_whole_buffers(self):
+        """A fusion that slices one operand and updates another in place
+        (a TPU scan body) moves the slice and twice the update."""
+        text = """HloModule tpu
+%fused (param_0: f32[64,1024], param_1: s32[], param_2: f32[64,1024]) -> f32[64,1024] {
+  %param_0 = f32[64,1024]{1,0:T(8,128)} parameter(0)
+  %param_1 = s32[]{:T(128)} parameter(1)
+  %param_2 = f32[64,1024]{1,0:T(8,128)} parameter(2)
+  %zero = s32[]{:T(128)} constant(0)
+  %row = f32[1,1024]{1,0:T(1,128)} dynamic-slice(%param_0, %param_1, %zero), dynamic_slice_sizes={1,1024}
+  %twice = f32[1,1024]{1,0:T(1,128)} add(%row, %row)
+  ROOT %dus = f32[64,1024]{1,0:T(8,128)} dynamic-update-slice(%param_2, %twice, %param_1, %zero)
+}
+ENTRY %main (x: f32[64,1024], i: s32[], acc: f32[64,1024]) -> f32[64,1024] {
+  %x = f32[64,1024]{1,0:T(8,128)} parameter(0)
+  %i = s32[]{:T(128)} parameter(1)
+  %acc = f32[64,1024]{1,0:T(8,128)} parameter(2)
+  ROOT %fusion = f32[64,1024]{1,0:T(8,128)} fusion(%x, %i, %acc), kind=kLoop, calls=%fused
+}
+"""
+        row = 1024 * 4
+        # read: the row of x, the index; written: the updated row, twice.
+        assert build_device_tree(text).total("bytes") == row + 4 + 2 * row
 
     def test_real_compiled_module_parses(self):
         def f(x, w):
@@ -162,7 +228,7 @@ class TestCollectives:
 
         if len(jax.devices()) < 2:
             pytest.skip("needs >1 device (run under forced host device count)")
-        mesh = jax.make_mesh((2,), ("model",), **axis_types_kw(1))
+        mesh = jax.make_mesh((2,), ("model",), axis_types=(jax.sharding.AxisType.Auto,))
 
         def f(x, w):
             return (x @ w).sum()

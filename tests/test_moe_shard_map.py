@@ -20,12 +20,12 @@ from repro.models.moe import moe, moe_spec
 from repro.models.moe_shard_map import moe_shard_map
 from repro.models.modules import init_params
 from repro.sharding.ctx import sharding_ctx
-from repro.launch.mesh import axis_types_kw
+from jax.sharding import AxisType
 
 cfg = get_config("deepseek-moe-16b", smoke=True)
 # high capacity so neither path drops tokens -> exact equivalence expected
 cfg = replace(cfg, capacity_factor=8.0, n_shared_experts=0)
-mesh = jax.make_mesh((2, 4), ("data", "model"), **axis_types_kw(2))
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 params = init_params(moe_spec(cfg), jax.random.key(0))
 B, S = 4, 16
 x = jax.random.normal(jax.random.key(1), (B, S, cfg.d_model), jnp.float32)

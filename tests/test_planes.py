@@ -18,16 +18,19 @@ import pytest
 from repro.core import CallTree, EpochMeta, TimelineReader, TimelineWriter, share_regressions
 from repro.core.export import export_tree, from_folded, to_folded, to_speedscope
 from repro.core.hlo_tree import build_device_tree, save_device_tree
+from repro.core.roofline import PEAKS, V5E_KIND, peaks_for
 from repro.core.planes import (
     DOMINANT_PREFIX,
     HLO_PREFIX,
     OCCUPANCY,
     PLANES,
+    TERM_PREFIX,
     PlaneError,
     annotate_tree,
     default_metric,
     dominant_term,
     missing_device_hint,
+    roofline_note,
     select_plane,
 )
 
@@ -54,7 +57,7 @@ ENTRY %main (p0: f32[4096,4096], p1: f32[4096,4096], p2: f32[4096,4096]) -> f32[
 
 
 def device_tree() -> CallTree:
-    return build_device_tree(HLO_TEXT)
+    return build_device_tree(HLO_TEXT, device_kind=V5E_KIND)
 
 
 def host_tree() -> CallTree:
@@ -136,6 +139,33 @@ class TestAnnotate:
         before = host.to_json()
         annotate_tree(host, device_tree())
         assert host.to_json() == before
+
+
+class TestPeaks:
+    def test_known_kind_resolves_to_its_entry(self):
+        hw = peaks_for("TPU v5 lite")
+        assert hw is PEAKS[V5E_KIND]
+        assert (hw.peak_flops, hw.hbm_bw, hw.hbm_bytes) == (197e12, 819e9, 16e9)
+        assert roofline_note(device_tree()) is None
+
+    @pytest.mark.parametrize("kind", ["cpu", "TPU v4", None])
+    def test_kind_without_entry_gets_no_roofline_terms(self, kind):
+        device = build_device_tree(HLO_TEXT, device_kind=kind)
+        merged = annotate_tree(host_tree(), device)
+        keys = {k for _p, n in merged.root.walk() for k in n.metrics}
+        assert HLO_PREFIX + "flops" in keys  # the cost counters still graft
+        assert not [k for k in keys if k.startswith((TERM_PREFIX, DOMINANT_PREFIX)) or k == OCCUPANCY]
+        assert peaks_for(kind) is None
+        assert repr(kind) in roofline_note(device)
+
+    def test_kind_travels_with_the_artifact(self, tmp_path):
+        from repro.core.hlo_tree import load_device_tree
+
+        path = str(tmp_path / "device_tree.json")
+        save_device_tree(build_device_tree(HLO_TEXT, device_kind="cpu"), path, meta={"arch": "x"})
+        with open(path) as f:
+            assert json.load(f)["meta"] == {"arch": "x", "device_kind": "cpu"}
+        assert load_device_tree(path).device_kind == "cpu"
 
 
 class TestSelectPlane:
@@ -228,6 +258,17 @@ class TestServerPlanes:
             server.stop()
 
 
+    def test_merged_plane_without_peaks_says_why(self, profile_dir):
+        save_device_tree(build_device_tree(HLO_TEXT, device_kind="cpu"), str(profile_dir / "device_tree.json"))
+        server = self._serve(profile_dir)
+        try:
+            code, html = _http_get(server.url + "/tree?plane=merged&fmt=html")
+            assert code == 200
+            assert "no roofline terms" in html
+        finally:
+            server.stop()
+
+
 class TestExportRoundtrip:
     def test_merged_folded_roundtrip(self):
         merged = annotate_tree(host_tree(), device_tree())
@@ -286,7 +327,7 @@ class TestTimelineSealRoundtrip:
             '  %scores2 = f32[4096,4096]{1,0} dot(%p0, %p1), lhs_contracting_dims={1}, '
             'rhs_contracting_dims={0}, metadata={op_name="jit(serve_step)/model/attention/scores"}\n'
         )
-        worse_device = build_device_tree(HLO_TEXT.replace("  %context", extra + "  %context"))
+        worse_device = build_device_tree(HLO_TEXT.replace("  %context", extra + "  %context"), device_kind=V5E_KIND)
         worse = annotate_tree(host_tree(), worse_device)
         sc = ("thread::MainThread", "py::serve_step", "py::model", "py::attention", "py::scores")
         assert _descend(worse, *sc).metrics[OCCUPANCY] > _descend(base, *sc).metrics[OCCUPANCY]
@@ -336,6 +377,18 @@ class TestCLIPlanes:
         back = from_folded(open(out).read(), OCCUPANCY)
         merged = annotate_tree(host_tree(), device_tree())
         assert back.total(OCCUPANCY) == pytest.approx(merged.total(OCCUPANCY))
+
+    def test_merged_plane_without_peaks_says_why(self, tmp_path):
+        d = tmp_path / "cpu"
+        d.mkdir()
+        (d / "tree.json").write_text(host_tree().to_json())
+        save_device_tree(build_device_tree(HLO_TEXT, device_kind="cpu"), str(d / "device_tree.json"))
+        r = self._run(
+            "export", str(d), "--plane", "merged", "--fmt", "folded",
+            "--metric", HLO_PREFIX + "flops", "--out", str(tmp_path / "m.folded"),
+        )
+        assert r.returncode == 0, (r.stdout, r.stderr)
+        assert "no roofline terms" in r.stderr and "'cpu'" in r.stderr
 
     def test_check_gates_on_device_plane_share(self, with_device):
         r = self._run(

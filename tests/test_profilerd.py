@@ -712,6 +712,39 @@ class TestSpoolAttachHardening:
         w2.close()
 
 
+class TestStaysOffTheAccelerator:
+    """The daemon runs beside a process that holds the chip, and the launcher
+    is the parent of one: neither may load JAX, whose first backend call
+    would claim the chip."""
+
+    def test_spawned_daemon_is_pinned_to_cpu(self, tmp_path, monkeypatch):
+        from repro.profilerd import daemon as daemon_mod
+
+        seen = {}
+
+        def fake_popen(cmd, **kw):
+            seen.update(kw["env"])
+            return None
+
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+        monkeypatch.setattr(subprocess, "Popen", fake_popen)
+        daemon_mod.spawn_attached_daemon(str(tmp_path / "t.spool"))
+        assert seen["JAX_PLATFORMS"] == "cpu"
+
+    def test_daemon_and_launcher_never_import_jax(self):
+        code = (
+            "import sys\n"
+            "import repro.launch.launcher, repro.profilerd.__main__, repro.profilerd.daemon\n"
+            "import repro.profilerd.server, repro.profilerd.aggregator, repro.core.planes\n"
+            "print(sorted(m for m in sys.modules if m.split('.')[0] in ('jax', 'jaxlib')))\n"
+        )
+        env = dict(os.environ)
+        env["PYTHONPATH"] = SRC_ROOT + os.pathsep + env.get("PYTHONPATH", "")
+        r = subprocess.run([sys.executable, "-c", code], env=env, capture_output=True, text=True, timeout=60)
+        assert r.returncode == 0, r.stderr
+        assert r.stdout.strip() == "[]"
+
+
 class TestDaemonLifecycle:
     def test_attach_sample_drain_stop_no_loss(self, tmp_path, parked):
         """Every stack the agent committed reaches the daemon's tree."""

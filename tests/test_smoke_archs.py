@@ -92,6 +92,20 @@ def test_grads_finite_and_nonzero(arch):
     assert any(float(jnp.abs(g).max()) > 0 for g in leaves), "all-zero gradients"
 
 
+def test_xlstm_grads_finite_at_full_chunk():
+    """At the full config's mLSTM chunk (256) the masked-out gate exponents
+    overflow; their gradients must still be finite."""
+    from dataclasses import replace
+
+    cfg = replace(get_config("xlstm-125m", smoke=True), chunk=get_config("xlstm-125m").chunk)
+    model = Model(cfg)
+    params = model.init(jax.random.key(0))
+    batch = make_batch(cfg, jax.random.key(1), batch=1, seq=cfg.chunk)
+    (loss, _), grads = jax.jit(jax.value_and_grad(model.loss, has_aux=True))(params, batch)
+    assert np.isfinite(float(loss))
+    assert all(np.isfinite(np.asarray(g, np.float32)).all() for g in jax.tree.leaves(grads))
+
+
 @pytest.mark.parametrize("arch", ARCHS)
 def test_decode_step(arch):
     cfg = get_config(arch, smoke=True)

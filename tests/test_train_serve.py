@@ -66,6 +66,28 @@ class TestTrainer:
         for s in (6, 8, 10):
             assert la[s] == pytest.approx(lb[s], rel=1e-4), f"divergence at step {s}"
 
+    def test_device_plane_written_with_device_kind(self, tmp_path):
+        import jax
+
+        from repro.core.hlo_tree import load_device_tree
+
+        Trainer(job(tmp_path, steps=1)).run()
+        tree = load_device_tree(str(tmp_path / "device_tree.json"))
+        assert tree.device_kind == jax.devices()[0].device_kind
+        assert tree.total("flops") > 0
+
+    def test_device_plane_failure_fails_the_run(self, tmp_path, monkeypatch):
+        from repro.core import hlo_tree
+
+        def broken(*_a, **_kw):
+            raise ValueError("unparseable HLO")
+
+        monkeypatch.setattr(hlo_tree, "tree_from_compiled", broken)
+        trainer = Trainer(job(tmp_path, steps=2))
+        with pytest.raises(ValueError, match="unparseable HLO"):
+            trainer.run()
+        assert trainer.step == 0
+
 
 class TestServer:
     def test_batched_serving_completes_requests(self):
