@@ -1,0 +1,1 @@
+"""Readers of per-layer metrics, one file each, found by the metric's name."""
