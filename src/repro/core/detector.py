@@ -31,6 +31,7 @@ from dataclasses import dataclass, field
 from collections.abc import Callable, Mapping, Sequence
 
 from .calltree import SAMPLES, CallTree
+from .spans import span
 
 
 @dataclass
@@ -396,6 +397,7 @@ class StragglerDetector:
 
 class WatchdogLoop:
     """Glue: sampler -> detector at a fixed cadence, on its own thread.
+    Each pass is a ``repro.watchdog.observe`` span (``repro.core.spans``).
 
     ``actions`` receive every event; a typical production wiring is
     ``[checkpoint_manager.save_emergency, launcher.report]`` — i.e. the
@@ -426,7 +428,8 @@ class WatchdogLoop:
     def _run(self) -> None:
         while not self._stop.wait(self.interval_s):
             try:
-                self.detector.observe(self.sampler.snapshot())
+                with span("repro.watchdog.observe"):
+                    self.detector.observe(self.sampler.snapshot())
             except Exception:
                 self.errors.append(traceback.format_exc())
 

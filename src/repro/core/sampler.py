@@ -10,9 +10,8 @@ target.  Two backends implement that contract here, selected by
   ``sys._current_frames()``, resolves "symbols" from code objects, classifies
   each frame by origin (``repro``/``jax``/``numpy``/``py``), merges each
   sample into a :class:`~repro.core.calltree.CallTree` on the fly, records a
-  ``(t, depth)`` timeline (paper Fig. 2), and optionally samples
-  ``/proc/self`` cpu/rss.  Cheap to wire up, but resolution/classification/
-  merging all burn target-process cycles.
+  ``(t, depth)`` timeline (paper Fig. 2).  Cheap to wire up, but
+  resolution/classification/merging all burn target-process cycles.
 
 * ``"daemon"`` — :class:`repro.profilerd.agent.DaemonBackend`, the paper's
   actual architecture: the target only publishes **raw, unresolved** frame
@@ -39,6 +38,7 @@ from collections.abc import Sequence
 from typing import Protocol, runtime_checkable
 
 from .calltree import CallTree
+from .spans import span
 
 # Default matches the paper (§V-E): 0.5 s balances detail vs overhead.
 DEFAULT_PERIOD_S = 0.5
@@ -89,16 +89,6 @@ def is_profiler_thread(name: str) -> bool:
     return name.startswith(PROFILER_THREAD_PREFIX)
 
 
-def open_psutil_process():
-    """The optional /proc rusage handle both backends sample, or None."""
-    try:
-        import psutil
-
-        return psutil.Process(os.getpid())
-    except Exception:  # pragma: no cover - psutil is optional
-        return None
-
-
 def collapse_stack(symbols: Sequence[str], collapse_origins: Sequence[str]) -> list[str]:
     """Fold runs of frames from ``collapse_origins`` into one ``origin::*`` node.
 
@@ -128,7 +118,6 @@ class SamplerConfig:
     # Collapse consecutive frames from these origins into one node.
     collapse_origins: tuple[str, ...] = ()
     record_timeline: bool = True
-    record_rusage: bool = True
     # -- backend seam ------------------------------------------------------
     # "thread": in-process helper thread (StackSampler).
     # "daemon": raw-frame publisher + out-of-process repro.profilerd daemon.
@@ -212,13 +201,6 @@ class TimelinePoint:
     thread: str
 
 
-@dataclass
-class RusagePoint:
-    t: float
-    cpu_s: float
-    rss_bytes: int
-
-
 class StackSampler:
     """The ``thread`` backend: sampling helper thread inside the target."""
 
@@ -231,13 +213,11 @@ class StackSampler:
         # per-frame dict bumps in add_stack.
         self._path_cache: dict[tuple, list] = {}
         self.timeline: list[TimelinePoint] = []
-        self.rusage: list[RusagePoint] = []
         self.n_samples = 0
         self._lock = threading.Lock()
         self._stop = threading.Event()
         self._thread: threading.Thread | None = None
         self._t0 = time.monotonic()
-        self._psutil_proc = open_psutil_process() if self.config.record_rusage else None
 
     # -- capture -----------------------------------------------------------------
 
@@ -252,37 +232,31 @@ class StackSampler:
         return collapse_stack(rev, self.config.collapse_origins)
 
     def _capture(self) -> None:
-        helper = self._thread.ident if self._thread is not None else None
-        names = {t.ident: t.name for t in threading.enumerate()}
-        now = time.monotonic() - self._t0
-        frames = sys._current_frames()
-        with self._lock:
-            for ident, frame in frames.items():
-                # Profiler infrastructure lives "outside the cgroup": neither
-                # the helper itself nor watchdog/report threads are profiled.
-                # (A synchronous sample_now() caller *is* profiled — it is
-                # target code asking for a sample of itself.)
-                if ident == helper or is_profiler_thread(names.get(ident, "")):
-                    continue
-                stack = self._stack_of(frame)
-                tname = names.get(ident, f"tid{ident}")
-                key = (tname, *stack)
-                chain = self._path_cache.get(key)
-                if chain is None:
-                    chain = self.tree.path_nodes([f"thread::{tname}"] + stack)
-                    if len(self._path_cache) < PATH_CACHE_CAP:
-                        self._path_cache[key] = chain
-                CallTree.add_stack_nodes(chain)
-                if self.config.record_timeline:
-                    self.timeline.append(TimelinePoint(now, len(stack), tname))
-            self.n_samples += 1
-            if self._psutil_proc is not None:
-                try:
-                    cpu = self._psutil_proc.cpu_times()
-                    rss = self._psutil_proc.memory_info().rss
-                    self.rusage.append(RusagePoint(now, cpu.user + cpu.system, rss))
-                except Exception:
-                    pass
+        with span("repro.profilerd.agent.tick"):
+            helper = self._thread.ident if self._thread is not None else None
+            names = {t.ident: t.name for t in threading.enumerate()}
+            now = time.monotonic() - self._t0
+            frames = sys._current_frames()
+            with self._lock:
+                for ident, frame in frames.items():
+                    # Profiler infrastructure lives "outside the cgroup": neither
+                    # the helper itself nor watchdog/report threads are profiled.
+                    # (A synchronous sample_now() caller *is* profiled — it is
+                    # target code asking for a sample of itself.)
+                    if ident == helper or is_profiler_thread(names.get(ident, "")):
+                        continue
+                    stack = self._stack_of(frame)
+                    tname = names.get(ident, f"tid{ident}")
+                    key = (tname, *stack)
+                    chain = self._path_cache.get(key)
+                    if chain is None:
+                        chain = self.tree.path_nodes([f"thread::{tname}"] + stack)
+                        if len(self._path_cache) < PATH_CACHE_CAP:
+                            self._path_cache[key] = chain
+                    CallTree.add_stack_nodes(chain)
+                    if self.config.record_timeline:
+                        self.timeline.append(TimelinePoint(now, len(stack), tname))
+                self.n_samples += 1
 
     def _run(self) -> None:
         while not self._stop.wait(self.config.period_s):

@@ -38,6 +38,7 @@ from repro.core import (
     make_sampler,
     write_report,
 )
+from repro.core.spans import since, snapshot, span
 from repro.data import DataConfig, Pipeline, SyntheticLM
 from repro.launch.compile_cache import use_compile_cache
 from repro.launch.steps import make_train_step
@@ -157,20 +158,22 @@ class Trainer:
         daemon dir (``REPRO_PROFILERD_OUT``) where the shared daemon's lazy
         discovery picks it up.  A failure here fails the run.
         """
-        self._step_exe = self._train_step.lower(self.params, self.opt_state, batch).compile()
+        with span("repro.train.compile"):
+            self._step_exe = self._train_step.lower(self.params, self.opt_state, batch).compile()
         if not self.job.profile:
             return
-        from repro.core.hlo_tree import save_device_tree, tree_from_compiled
+        with span("repro.train.device_plane"):
+            from repro.core.hlo_tree import save_device_tree, tree_from_compiled
 
-        tree = tree_from_compiled(self._step_exe, device_kind=jax.devices()[0].device_kind)
-        dests = [os.path.join(self.job.out_dir, "device_tree.json")]
-        env_out = os.environ.get("REPRO_PROFILERD_OUT")
-        if env_out:
-            dests.append(os.path.join(env_out, "device_tree.json"))
-        for p in dests:
-            os.makedirs(os.path.dirname(p) or ".", exist_ok=True)
-            save_device_tree(tree, p, meta={"arch": self.cfg.name, "source": "train"})
-        print(f"[train] device plane: {dests[0]} ({tree.node_count()} call sites, {tree.device_kind})")
+            tree = tree_from_compiled(self._step_exe, device_kind=jax.devices()[0].device_kind)
+            dests = [os.path.join(self.job.out_dir, "device_tree.json")]
+            env_out = os.environ.get("REPRO_PROFILERD_OUT")
+            if env_out:
+                dests.append(os.path.join(env_out, "device_tree.json"))
+            for p in dests:
+                os.makedirs(os.path.dirname(p) or ".", exist_ok=True)
+                save_device_tree(tree, p, meta={"arch": self.cfg.name, "source": "train"})
+            print(f"[train] device plane: {dests[0]} ({tree.node_count()} call sites, {tree.device_kind})")
 
     def _state_tree(self) -> dict:
         return {
@@ -197,31 +200,49 @@ class Trainer:
     # -- loop --------------------------------------------------------------------------
 
     def run(self) -> dict:
+        """Train to ``job.steps``.  Each iteration is a ``repro.train.step``
+        span holding ``data``, ``dispatch``, ``bookkeeping`` (``checkpoint``
+        inside it), ``wait`` and ``fetch`` (``repro.core.spans``); the
+        summary's ``spans`` is their tally over this call."""
+        spans0 = snapshot()
         self.initialize()
         if self.sampler:
             self.sampler.start()
         if self.watchdog:
             self.watchdog.start()
         t0 = time.time()
+        n_fetched, t_first, t_last = 0, 0.0, 0.0  # steps fetched; perf_counter at the end of the first, the last
         try:
             while self.step < self.job.steps:
-                batch = {k: jnp.asarray(v) for k, v in next(self.data).items()}
-                if self._step_exe is None:
-                    # Before the step call: donation invalidates the argument
-                    # buffers, and lowering only needs their avals anyway.
-                    self._compile_step(batch)
-                self.params, self.opt_state, metrics = self._step_exe(
-                    self.params, self.opt_state, batch
-                )
-                self.step += 1
-                self._touch_heartbeat()
-                if self.step % self.job.ckpt_every == 0 or self.step == self.job.steps:
-                    self.ckpt.save(self.step, self._state_tree())
-                m = {k: float(v) for k, v in metrics.items() if jnp.ndim(v) == 0}
-                m["step"] = self.step
-                self.metrics_log.append(m)
-                if self.step % 5 == 0 or self.step == 1:
-                    print(f"[train] step {self.step}: loss={m['loss']:.4f} lr={m['lr']:.2e}")
+                with span("repro.train.step", step=self.step):
+                    with span("repro.train.data"):
+                        batch = {k: jnp.asarray(v) for k, v in next(self.data).items()}
+                    if self._step_exe is None:
+                        # Before the step call: donation invalidates the argument
+                        # buffers, and lowering only needs their avals anyway.
+                        self._compile_step(batch)
+                    with span("repro.train.dispatch"):
+                        self.params, self.opt_state, metrics = self._step_exe(
+                            self.params, self.opt_state, batch
+                        )
+                    self.step += 1
+                    with span("repro.train.bookkeeping"):
+                        self._touch_heartbeat()
+                        if self.step % self.job.ckpt_every == 0 or self.step == self.job.steps:
+                            with span("repro.train.checkpoint"):
+                                self.ckpt.save(self.step, self._state_tree())
+                    with span("repro.train.wait"):
+                        jax.block_until_ready(metrics)
+                    with span("repro.train.fetch"):
+                        m = {k: float(v) for k, v in metrics.items() if jnp.ndim(v) == 0}
+                        m["step"] = self.step
+                        self.metrics_log.append(m)
+                        if self.step % 5 == 0 or self.step == 1:
+                            print(f"[train] step {self.step}: loss={m['loss']:.4f} lr={m['lr']:.2e}")
+                    t_last = time.perf_counter()
+                    if not n_fetched:
+                        t_first = t_last
+                    n_fetched += 1
         finally:
             if self.watchdog:
                 self.watchdog.stop()
@@ -229,15 +250,18 @@ class Trainer:
             self.ckpt.wait()
             self.data.close()
         wall = time.time() - t0
-        tokens = self.step * self.job.global_batch * self.job.seq_len
+        # Steps 2..N of this call, from the end of step 1's fetch to the end of
+        # the last: the first step's compile and device-plane costing are set-up.
+        steady = (n_fetched - 1) * self.job.global_batch * self.job.seq_len
         summary = {
             "arch": self.cfg.name,
             "steps": self.step,
             "wall_s": wall,
-            "tokens_per_s": tokens / max(wall, 1e-9),
+            "tokens_per_s": steady / (t_last - t_first) if steady > 0 else None,
             "final_loss": self.metrics_log[-1]["loss"] if self.metrics_log else None,
             "first_loss": self.metrics_log[0]["loss"] if self.metrics_log else None,
             "anomalies": [e.describe() for e in self.anomalies],
+            "spans": since(spans0),
         }
         with open(os.path.join(self.job.out_dir, "metrics.json"), "w") as f:
             json.dump({"summary": summary, "steps": self.metrics_log}, f, indent=1)

@@ -26,10 +26,11 @@ import threading
 import time
 
 from repro.core.calltree import CallNode, CallTree
-from repro.core.sampler import SamplerConfig, is_profiler_thread, open_psutil_process
+from repro.core.sampler import SamplerConfig, is_profiler_thread
+from repro.core.spans import span
 
 from .spool import SpoolWriter
-from .wire import WIRE_VERSION, Encoder, RawFrame, RawSample, Rusage
+from .wire import WIRE_VERSION, Encoder, RawFrame, RawSample
 
 
 class Agent:
@@ -47,13 +48,11 @@ class Agent:
         period_s: float = 0.5,
         max_depth: int = 256,
         spool_bytes: int = 4 << 20,
-        record_rusage: bool = False,
         wire_version: int = WIRE_VERSION,
     ):
         self.spool_path = spool_path
         self.period_s = period_s
         self.max_depth = max_depth
-        self.record_rusage = record_rusage
         self._writer = SpoolWriter(spool_path, spool_bytes)
         self._enc = Encoder(version=wire_version)
         # Encoder + SpoolWriter are single-writer; sample_now() may race the
@@ -65,7 +64,6 @@ class Agent:
         self.n_ticks = 0
         self.n_stacks = 0  # stacks offered to the spool (dropped ones included)
         self.n_dropped_batches = 0
-        self._psutil_proc = open_psutil_process() if record_rusage else None
         self._writer.write(self._enc.encode_hello(os.getpid(), period_s))
 
     # -- capture -----------------------------------------------------------
@@ -85,36 +83,31 @@ class Agent:
 
     def tick(self) -> int:
         """Capture one snapshot of every thread and publish it. Returns the
-        number of stacks in the batch (0 if the batch was dropped)."""
-        helper = self._thread.ident if self._thread is not None else None
-        names = {t.ident: t.name for t in threading.enumerate()}
-        now = time.monotonic() - self._t0
-        frames = sys._current_frames()
-        samples = []
-        for ident, frame in frames.items():
-            # Same exclusion rule as the thread backend: profiler
-            # infrastructure (this publisher, watchdog threads) is invisible.
-            if ident == helper or is_profiler_thread(names.get(ident, "")):
-                continue
-            samples.append(
-                RawSample(now, ident, names.get(ident, f"tid{ident}"), self._raw_stack(frame))
-            )
-        rusage = None
-        if self._psutil_proc is not None:
-            try:
-                cpu = self._psutil_proc.cpu_times()
-                rusage = Rusage(now, cpu.user + cpu.system, self._psutil_proc.memory_info().rss)
-            except Exception:
-                rusage = None
-        with self._tick_lock:
-            payload, fresh = self._enc.encode_tick(samples, rusage)
-            self.n_ticks += 1
-            self.n_stacks += len(samples)
-            if not self._writer.write(payload):
-                self._enc.rollback(fresh)
-                self.n_dropped_batches += 1
-                return 0
-        return len(samples)
+        number of stacks in the batch (0 if the batch was dropped).  Each
+        tick is a ``repro.profilerd.agent.tick`` span (``repro.core.spans``)."""
+        with span("repro.profilerd.agent.tick"):
+            helper = self._thread.ident if self._thread is not None else None
+            names = {t.ident: t.name for t in threading.enumerate()}
+            now = time.monotonic() - self._t0
+            frames = sys._current_frames()
+            samples = []
+            for ident, frame in frames.items():
+                # Same exclusion rule as the thread backend: profiler
+                # infrastructure (this publisher, watchdog threads) is invisible.
+                if ident == helper or is_profiler_thread(names.get(ident, "")):
+                    continue
+                samples.append(
+                    RawSample(now, ident, names.get(ident, f"tid{ident}"), self._raw_stack(frame))
+                )
+            with self._tick_lock:
+                payload, fresh = self._enc.encode_tick(samples)
+                self.n_ticks += 1
+                self.n_stacks += len(samples)
+                if not self._writer.write(payload):
+                    self._enc.rollback(fresh)
+                    self.n_dropped_batches += 1
+                    return 0
+            return len(samples)
 
     def _run(self) -> None:
         while not self._stop.wait(self.period_s):
@@ -188,7 +181,6 @@ class DaemonBackend:
             period_s=self.config.period_s,
             max_depth=self.config.max_depth,
             spool_bytes=self.config.spool_bytes,
-            record_rusage=self.config.record_rusage,
             wire_version=self.config.wire_version,
         )
         self.agent.start()

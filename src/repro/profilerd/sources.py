@@ -39,7 +39,7 @@ from repro.core.snapshot import EpochMeta, TimelineWriter
 
 from .pipeline import IngestPipeline
 from .spool import SpoolError, SpoolReader
-from .wire import Bye, Hello, Rusage
+from .wire import Bye, Hello
 
 STALLED = "TARGET_STALLED"
 RESUMED = "TARGET_RESUMED"
@@ -103,7 +103,6 @@ class SpoolSource:
             depth_timeline=deque(maxlen=timeline_cap),  # (t, depth)
         )
         self.tree = self.pipeline.tree
-        self.rusage: deque = deque(maxlen=timeline_cap)
         self.target_pid = self.reader.writer_pid
         self.period_s = 0.0
         self.wire_version = 0  # from HELLO; 0 until the target announced
@@ -177,13 +176,12 @@ class SpoolSource:
     # -- ingest --------------------------------------------------------------
 
     def _apply(self, ev) -> None:
-        """Target policy for the pipeline's non-sample events."""
+        """Target policy for the pipeline's non-sample events (RUSAGE
+        records, from old agents, are skipped)."""
         if isinstance(ev, Hello):
             self.target_pid = ev.pid
             self.period_s = ev.period_s
             self.wire_version = ev.version
-        elif isinstance(ev, Rusage):
-            self.rusage.append((ev.t, ev.cpu_s, ev.rss_bytes))
         elif isinstance(ev, Bye):
             self.bye_seen = True
             self.n_ticks_reported += ev.n_ticks

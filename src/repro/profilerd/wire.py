@@ -33,7 +33,8 @@ kind   name       payload
 3      SAMPLE     (wire v1) f64 t, u64 tid, u32 thread_name_id, u16 nframes,
                   nframes * (u32 file_id, u32 func_id, u32 lineno);
                   frames ordered root -> leaf
-4      RUSAGE     f64 t, f64 cpu_s, u64 rss_bytes
+4      RUSAGE     f64 t, f64 cpu_s, u64 rss_bytes (decoded from old spools;
+                  the agent no longer sends it)
 5      BYE        u64 n_ticks (publisher ticks over the whole session)
 6      STACKDEF   (wire v2) u32 stack_id, u16 n_prefix, u16 n_new,
                   n_new * (u32 file_id, u32 func_id, u32 lineno).

@@ -38,6 +38,22 @@ class TestTrainer:
         # host-plane profile written (the always-on paper toolchain)
         assert os.path.exists(tmp_path / "host_profile.html")
 
+    def test_loop_spans_in_metrics_json(self, tmp_path):
+        """Every step records each loop span once; set-up records its own;
+        the summary's rate leaves the first step (compile, costing) out."""
+        summary = Trainer(job(tmp_path, steps=3, ckpt_every=2)).run()
+        with open(tmp_path / "metrics.json") as f:
+            got = json.load(f)["summary"]["spans"]
+        for name in ("step", "data", "dispatch", "bookkeeping", "wait", "fetch"):
+            assert got[f"repro.train.{name}"]["count"] == 3, name
+        assert got["repro.train.checkpoint"]["count"] == 2  # step 2, and step 3 as the last
+        assert got["repro.train.compile"]["count"] == got["repro.train.device_plane"]["count"] == 1
+        steps = got["repro.train.step"]["total_s"]
+        assert got["repro.train.compile"]["total_s"] + got["repro.train.device_plane"]["total_s"] < steps
+        # steps 2 and 3 over the time between their fetches, which leaves the compile out
+        assert summary["tokens_per_s"] > 3 * 4 * 32 / summary["wall_s"]
+        assert summary["tokens_per_s"] >= 2 * 4 * 32 / steps
+
     def test_checkpoint_resume_exact(self, tmp_path):
         t1 = Trainer(job(tmp_path, steps=6))
         t1.run()
