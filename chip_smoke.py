@@ -12,8 +12,9 @@ a user calls:
   with the out-of-process profiler daemon sampling beside it;
 * serve: ``repro.launch.serve.BatchedServer`` on the same config, 8 requests
   on a decode batch of 8;
-* kernels: the Pallas flash-attention and RG-LRU scan kernels compiled for the
-  chip (never interpreted) and compared with ``repro.kernels.ref``.
+* kernels: the Pallas flash-attention, splash-attention and RG-LRU scan
+  kernels compiled for the chip (never interpreted) and compared with
+  ``repro.kernels.ref``.
 
 Weights and data are random, made from fixed seeds.  Each phase checks what
 it produced; a failed phase makes the script exit 1 after the others ran.
@@ -138,18 +139,26 @@ def kernel_phase() -> None:
     from repro.kernels import ops, ref
 
     key = jax.random.key(0)
-    # (label, batch, seq, q heads, kv heads, head dim, window)
+
+    def flash(q, k, v, window):
+        return ops.flash_attention.lower(q, k, v, causal=True, window=window, interpret=False)
+
+    def splash(q, k, v, window):
+        return ops.splash_attention.lower(q, k, v, window=window)
+
+    # (label, wrapper, batch, seq, q heads, kv heads, head dim, window)
     attention_cases = [
-        ("flash_attention causal, qwen3-4b widths", 1, 2048, 32, 8, 128, None),
-        ("flash_attention windowed, recurrentgemma-9b local attention", 1, 4096, 16, 1, 256, 2048),
-        ("flash_attention causal, short seq 64", 1, 64, 32, 8, 128, None),
+        ("flash_attention causal, qwen3-4b widths", flash, 1, 2048, 32, 8, 128, None),
+        ("flash_attention windowed, recurrentgemma-9b local attention", flash, 1, 4096, 16, 1, 256, 2048),
+        ("flash_attention causal, short seq 64", flash, 1, 64, 32, 8, 128, None),
+        ("splash_attention causal, granite-3-8b widths", splash, 1, 4096, 32, 8, 128, None),
     ]
-    for i, (label, b, s, hq, hkv, d, window) in enumerate(attention_cases):
+    for i, (label, lower, b, s, hq, hkv, d, window) in enumerate(attention_cases):
         kq, kk, kv = jax.random.split(jax.random.fold_in(key, i), 3)
         q = jax.random.normal(kq, (b, s, hq, d), jnp.bfloat16)
         k = jax.random.normal(kk, (b, s, hkv, d), jnp.bfloat16)
         v = jax.random.normal(kv, (b, s, hkv, d), jnp.bfloat16)
-        compiled = ops.flash_attention.lower(q, k, v, causal=True, window=window, interpret=False).compile()
+        compiled = lower(q, k, v, window).compile()
         check("tpu_custom_call" in compiled.as_text(), f"{label}: no tpu_custom_call in the compiled program")
         got = compiled(q, k, v)
         with jax.default_matmul_precision("highest"):

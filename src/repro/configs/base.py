@@ -50,7 +50,7 @@ class ModelConfig:
     chunk: int = 512  # q-chunk (attention) / time-chunk (mLSTM)
     chunk_threshold: int = 8192  # switch to chunked attention above this seq len
     attn_cp: bool = False  # context-parallel q-chunks (for TP-unshardable heads)
-    attention_impl: str = "xla"  # xla | pallas | pallas_interpret
+    attention_impl: str = "auto"  # auto (splash on a TPU where it fits) | xla | pallas | pallas_interpret
     remat: str = "full"  # none | full | dots
     input_mode: str = "tokens"  # tokens | embeddings (vlm/audio frontend stubs)
     logit_softcap: float = 0.0

@@ -9,6 +9,7 @@ Mosaic. The XLA reference path used by the dry-run lives in the model code.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +17,7 @@ import jax.numpy as jnp
 from .flash_attention import flash_attention_bhsd
 from .fused_rmsnorm import fused_rmsnorm_pallas
 from .rglru_scan import rglru_scan_pallas
+from .splash_attention import splash_blocks, splash_fits, splash_kernel
 
 
 @functools.partial(
@@ -40,6 +42,31 @@ def flash_attention(
             qh, kh, vh, causal=causal, window=window, block_q=block_q, block_k=block_k,
             interpret=interpret,
         )
+        return jnp.swapaxes(o, 1, 2)
+
+
+@functools.partial(jax.jit, static_argnames=("window", "interpret"))
+def splash_attention(
+    q: jax.Array,  # (B, S, Hq, D)
+    k: jax.Array,  # (B, T, Hkv, D)
+    v: jax.Array,
+    *,
+    window: int | None = None,
+    interpret: bool = False,
+) -> jax.Array:
+    """Causal (optionally sliding-window) attention through JAX's splash
+    kernel, differentiable: GQA without repeating K/V, fully-masked blocks
+    skipped in compute and DMA.  Scores are q.k / sqrt(D); softmax statistics
+    and accumulators are float32 inside the kernel."""
+    B, S, Hq, D = q.shape
+    T, Hkv = k.shape[1], k.shape[2]
+    if not splash_fits(S, T, Hq, Hkv, D):
+        raise ValueError(f"splash_attention does not take S={S}, T={T}, Hq={Hq}, Hkv={Hkv}, D={D}")
+    with jax.named_scope("splash_attention"):
+        kernel = splash_kernel(S, T, Hq, window, splash_blocks(S, T), interpret)
+        # The kernel does not scale the scores: scale q once, in float32.
+        q = (q.astype(jnp.float32) * (1.0 / math.sqrt(D))).astype(q.dtype)
+        o = jax.vmap(kernel)(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2))
         return jnp.swapaxes(o, 1, 2)
 
 

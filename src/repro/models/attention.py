@@ -1,15 +1,19 @@
 """Attention: GQA/MQA with RoPE/M-RoPE, qk-norm, sliding windows, KV cache.
 
-Three execution paths, selected by ``cfg.attention_impl``:
+The training/prefill core takes one of three paths, chosen by
+:func:`attention_path` from the platform and the shapes:
 
-* ``xla``              — pure-jnp math (reference; what the dry-run lowers,
-                         since TPU Pallas cannot be compiled by the CPU backend);
-* ``pallas``           — Pallas flash kernel (TPU target);
-* ``pallas_interpret`` — same kernel, interpret mode (CPU correctness tests).
+* ``splash``  — JAX's block-sparse splash kernel (``kernels.ops.splash_attention``)
+                on a TPU where the shapes fit it;
+* ``full``    — pure-jnp math that materializes the S×T scores (the reference,
+                and what the CPU and the dry-run run);
+* ``chunked`` — the same math as an online softmax over query chunks above
+                ``cfg.chunk_threshold``, so 32k-token prefill never holds the
+                full score matrix.
 
-The xla path switches to a **chunked** (online-softmax over query blocks)
-variant above ``cfg.chunk_threshold`` so 32k-token prefill never materializes
-the full S×S score matrix — same math as the flash kernel, scan-based.
+``cfg.attention_impl`` is ``auto`` (this rule); ``xla`` forces the jnp paths;
+``pallas`` and ``pallas_interpret`` force the splash kernel, compiled or in
+interpret mode (CPU correctness tests).
 """
 
 from __future__ import annotations
@@ -19,7 +23,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.sharding.ctx import shard_activation
+from repro.sharding.ctx import current_sharding_ctx, shard_activation
 
 from .modules import ArraySpec, apply_mrope, apply_rope, rms_norm, rms_norm_spec
 
@@ -144,19 +148,34 @@ def _attend_chunked(q, k, v, cfg, *, window: int | None = None):
     return o.reshape(B, S, Hq, D)
 
 
+def attention_path(cfg, S: int, T: int, Hq: int, Hkv: int, D: int, platform: str) -> str:
+    """The training/prefill attention core for these shapes: ``splash``,
+    ``chunked`` or ``full``.  ``auto`` takes the kernel on a TPU when its
+    tiles fit the shapes and no mesh of several devices is installed (a
+    Mosaic kernel is not partitioned for one)."""
+    impl = cfg.attention_impl
+    if impl in ("pallas", "pallas_interpret"):
+        return "splash"
+    if impl == "auto" and platform == "tpu":
+        from repro.kernels.splash_attention import splash_fits  # Pallas: imported only where it can run
+
+        mesh, _ = current_sharding_ctx()
+        if splash_fits(S, T, Hq, Hkv, D) and (mesh is None or mesh.size == 1):
+            return "splash"
+    return "chunked" if S > cfg.chunk_threshold else "full"
+
+
 def attention(params, x, cfg, positions, *, window: int | None = None, scope: str = "attention"):
     """Training/prefill self-attention. x: (B,S,D) -> (B,S,D)."""
     with jax.named_scope(scope):
         q, k, v = _project_qkv(params, x, cfg, positions)
-        impl = cfg.attention_impl
-        S = x.shape[1]
-        if impl in ("pallas", "pallas_interpret"):
+        _, S, Hq, D = q.shape
+        path = attention_path(cfg, S, k.shape[1], Hq, k.shape[2], D, jax.default_backend())
+        if path == "splash":
             from repro.kernels import ops as kops
 
-            o = kops.flash_attention(
-                q, k, v, causal=True, window=window, interpret=(impl == "pallas_interpret")
-            )
-        elif S > cfg.chunk_threshold:
+            o = kops.splash_attention(q, k, v, window=window, interpret=cfg.attention_impl == "pallas_interpret")
+        elif path == "chunked":
             o = _attend_chunked(q, k, v, cfg, window=window)
         else:
             o = _attend_full(q, k, v, cfg, window=window)

@@ -146,7 +146,7 @@ def recurrent_block(params, x, cfg, *, scope: str = "recurrent_block"):
         xb = causal_conv1d(params, xb)
         h, _ = rglru(
             params["lru"], xb, chunk=cfg.chunk,
-            impl=cfg.attention_impl if cfg.attention_impl != "xla" else "xla",
+            impl="xla" if cfg.attention_impl == "auto" else cfg.attention_impl,
         )
         with jax.named_scope("gate"):
             y = h * jax.nn.gelu(gb, approximate=True)

@@ -111,6 +111,131 @@ def test_flash_matches_model_xla_path():
 
 
 # ---------------------------------------------------------------------------
+# splash attention (the training path on a TPU) and the path selection
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize(
+    "Hq,Hkv,window",
+    [
+        (4, 2, None),  # GQA, causal
+        (4, 1, None),  # MQA, causal
+        (4, 2, 64),  # GQA, sliding window
+    ],
+)
+def test_splash_matches_model_full_path(Hq, Hkv, window):
+    """Output and the gradients of q, k and v against the model's XLA path in
+    float32, from the same bf16 inputs: the kernel rounds its output and
+    gradients to bf16 (2**-9 of each) and scales q in bf16 once."""
+    from repro.configs import get_config
+    from repro.models.attention import _attend_full
+
+    cfg = get_config("granite-3-8b", smoke=True)
+    B, S, D = 1, 256, 128
+    ks = jax.random.split(jax.random.key(11), 4)
+    q = jax.random.normal(ks[0], (B, S, Hq, D), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (B, S, Hkv, D), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (B, S, Hkv, D), jnp.bfloat16)
+    do = jax.random.normal(ks[3], (B, S, Hq, D), jnp.float32)
+
+    def kernel_loss(q, k, v):
+        o = ops.splash_attention(q, k, v, window=window, interpret=True)
+        return jnp.sum(o.astype(jnp.float32) * do), o
+
+    def ref_loss(q, k, v):
+        o = _attend_full(q, k, v, cfg, window=window)
+        return jnp.sum(o * do), o
+
+    (_, got), g_got = jax.value_and_grad(kernel_loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    f32 = [a.astype(jnp.float32) for a in (q, k, v)]
+    (_, want), g_want = jax.value_and_grad(ref_loss, argnums=(0, 1, 2), has_aux=True)(*f32)
+    allclose(got, want, jnp.bfloat16)
+    for a, b in zip(g_got, g_want):
+        assert a.shape == b.shape
+        rel = float(jnp.linalg.norm(a.astype(jnp.float32) - b) / jnp.linalg.norm(b))
+        assert rel < 1e-2, rel
+    if window is not None:  # the window is applied: a causal-only reference is far off
+        causal = _attend_full(*f32, cfg)
+        assert float(jnp.max(jnp.abs(got.astype(jnp.float32) - causal))) > 0.1
+
+
+def test_model_attention_through_splash_matches_xla():
+    """``attention()`` under ``pallas_interpret`` runs the kernel and agrees
+    with the same layer under ``xla``."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models.attention import attention, attention_spec
+    from repro.models.modules import init_params
+
+    cfg = dataclasses.replace(get_config("granite-3-8b", smoke=True), d_model=256, n_heads=2, n_kv_heads=1,
+                              head_dim_=128)
+    params = init_params(attention_spec(cfg), jax.random.key(12))
+    x = jax.random.normal(jax.random.key(13), (1, 128, cfg.d_model), jnp.float32)
+    pos = jnp.arange(128)[None]
+    want = attention(params, x, dataclasses.replace(cfg, attention_impl="xla"), pos)
+    got = attention(params, x, dataclasses.replace(cfg, attention_impl="pallas_interpret"), pos)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-2, atol=2e-2)
+
+
+def test_attention_path_follows_platform_and_shapes():
+    import dataclasses
+    from types import SimpleNamespace
+
+    from repro.configs import get_config, list_archs
+    from repro.models.attention import attention_path
+    from repro.sharding.ctx import sharding_ctx
+
+    granite = get_config("granite-3-8b")  # 32 q and 8 kv heads of 128
+    assert granite.attention_impl == "auto"
+    # The CPU keeps the XLA paths for every configuration, as before the kernel.
+    for arch in list_archs():
+        cfg = get_config(arch)
+        for S in (64, 4096, 32768):
+            want = "chunked" if S > cfg.chunk_threshold else "full"
+            assert attention_path(cfg, S, S, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, "cpu") == want
+    # A TPU takes the kernel where its tiles fit, and the XLA path elsewhere.
+    assert attention_path(granite, 4096, 4096, 32, 8, 128, "tpu") == "splash"
+    assert attention_path(granite, 32768, 32768, 32, 8, 128, "tpu") == "splash"
+    assert attention_path(granite, 384, 384, 32, 8, 128, "tpu") == "splash"  # 128-wide tiles
+    assert attention_path(granite, 64, 64, 32, 8, 128, "tpu") == "full"  # shorter than one tile
+    assert attention_path(granite, 4096, 4096, 32, 8, 64, "tpu") == "full"  # D=64 (musicgen)
+    assert attention_path(granite, 4096, 4096, 12, 8, 128, "tpu") == "full"  # heads do not group
+    xla = dataclasses.replace(granite, attention_impl="xla")
+    assert attention_path(xla, 4096, 4096, 32, 8, 128, "tpu") == "full"
+    assert attention_path(xla, 16384, 16384, 32, 8, 128, "tpu") == "chunked"
+    forced = dataclasses.replace(granite, attention_impl="pallas_interpret")
+    assert attention_path(forced, 4096, 4096, 32, 8, 128, "cpu") == "splash"
+    # Under a mesh of several devices the kernel would not be partitioned.
+    with sharding_ctx(SimpleNamespace(size=4), {}):
+        assert attention_path(granite, 4096, 4096, 32, 8, 128, "tpu") == "full"
+    with sharding_ctx(SimpleNamespace(size=1), {}):
+        assert attention_path(granite, 4096, 4096, 32, 8, 128, "tpu") == "splash"
+
+
+def test_rglru_scan_stays_xla_under_auto(monkeypatch):
+    """The RG-LRU scan reads ``attention_impl`` as its own impl: ``auto``
+    keeps its XLA scan."""
+    import repro.models.rglru as rg
+    from repro.configs import get_config
+    from repro.models.modules import init_params
+
+    seen = []
+    real = rg.rglru
+
+    def spy(*args, impl="xla", **kw):
+        seen.append(impl)
+        return real(*args, impl=impl, **kw)
+
+    monkeypatch.setattr(rg, "rglru", spy)
+    cfg = get_config("recurrentgemma-9b", smoke=True)
+    assert cfg.attention_impl == "auto"
+    params = init_params(rg.recurrent_block_spec(cfg), jax.random.key(14))
+    rg.recurrent_block(params, jnp.ones((1, 16, cfg.d_model), jnp.float32), cfg)
+    assert seen == ["xla"]
+
+
+# ---------------------------------------------------------------------------
 # RG-LRU scan
 # ---------------------------------------------------------------------------
 

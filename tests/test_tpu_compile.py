@@ -62,6 +62,30 @@ def test_flash_attention_compiles(one_chip, batch, seq, q_heads, kv_heads, head_
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize(
+    "seq,q_heads,kv_heads,head_dim,window",
+    [
+        (4096, 32, 8, 128, None),  # granite-3-8b causal GQA
+        (4096, 8, 1, 256, None),  # gemma-2b causal MQA
+        (4096, 16, 1, 256, 2048),  # recurrentgemma-9b local attention
+    ],
+)
+def test_splash_attention_and_its_gradient_compile(one_chip, seq, q_heads, kv_heads, head_dim, window):
+    q = _spec((1, seq, q_heads, head_dim), jnp.bfloat16, one_chip)
+    kv = _spec((1, seq, kv_heads, head_dim), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(ops.splash_attention(q, k, v, window=window).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile()
+    text = compiled.as_text()
+    # the forward kernel and the fused dq+dkv kernel, each custom-call on one
+    # line of the HLO text with its scope path
+    kernels = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(kernels) == 2
+    assert all('op_name="' in ln and "splash_attention/" in ln for ln in kernels)
+
+
 def test_rglru_scan_compiles(one_chip):
     x = _spec((2, 2048, 4096), jnp.float32, one_chip)
     compiled = ops.rglru_scan.lower(x, x, interpret=False).compile()
