@@ -57,7 +57,8 @@ def main(argv=None) -> int:
     model, B, S = config["model"], int(traffic["batch"]), int(traffic["seq_len"])
     n = int(traffic["check_steps"])
     hyper = {"lr": float(traffic["lr"]), "warmup": int(traffic["lr_warmup"]), "total_steps": ENDLESS}
-    weights = jax.jit(lambda k: layout.init_params(model, k))
+    family = layout.family(config["reference"])
+    weights = jax.jit(lambda k: layout.init_params(family, model, k))
     make_batch = batch_maker(B, S, model["vocab"])
 
     lowp = jnp.dtype(config["dtypes"]["control"])

@@ -161,6 +161,7 @@ def run(ctx: Context) -> Outcome:
         raise BenchError("warmup_steps must exceed check_steps")
     window_len = min(ctx.seconds, float(tr.get("trace_seconds", ctx.seconds))) if ctx.trace else ctx.seconds
     name = ctx.config["name"]
+    family = layout.family(ctx.config["reference"])
     cfg = model_config(model)
     list_archs()  # load the program's own configurations first, so this one is not overwritten
     register(name, lambda: cfg, lambda: cfg)
@@ -168,7 +169,7 @@ def run(ctx: Context) -> Outcome:
 
     key = seed_key(ctx.seed)
     wkey, dkey = jax.random.fold_in(key, 0), jax.random.fold_in(key, 1)
-    weights = jax.jit(lambda k: layout.init_params(model, k))
+    weights = jax.jit(lambda k: layout.init_params(family, model, k))
     make_batch = batch_maker(B, S, cfg.vocab)
 
     norms = jax.jit(leaf_norms)
@@ -291,7 +292,7 @@ def run(ctx: Context) -> Outcome:
         for k in ("agent", "daemon"):
             a, b = st["cpu0"][k], st["cpu1"][k]
             counters[k] = None if a is None or b is None else b - a
-        readings = Readings(trace, steps, steps * B * S, window_s, model, tr,
+        readings = Readings(trace, steps, steps * B * S, window_s, model, ctx.config["reference"], tr,
                             peaks_for(dev.device_kind), counters)
         bd = breakdown(trace)
         keep = os.environ.get("CHIPBENCH_KEEP_TRACE")
@@ -299,7 +300,7 @@ def run(ctx: Context) -> Outcome:
             trace.save(keep)
             with open(keep.replace(".trace.json.gz", ".readings.json"), "w") as f:
                 json.dump({k: getattr(readings, k) for k in
-                           ("steps", "tokens", "window_s", "model", "traffic", "peaks", "counters")}, f)
+                           ("steps", "tokens", "window_s", "model", "reference", "traffic", "peaks", "counters")}, f)
     shutil.rmtree(out_dir, ignore_errors=True)
 
     batches = [make_batch(dkey, k) for k in range(check_steps)]

@@ -3,8 +3,11 @@
 Everything a cell needs is found by name from ``BENCHMARK.json``:
 
 * the configuration ``chipbench/configs/<config>.json``: the model's sizes
-  (``model``), the plain reference beside it (``reference``), its source and
-  cuts;
+  (``model``), its model family (``reference``), its source and cuts;
+* the family ``chipbench/reference/<reference>.py``: the plain reference
+  (``loss``), what one layer holds (``layer_shapes``) and needs
+  (``layer_params``, ``forward_flops_per_token``) by its kind and FFN kind,
+  and the cuts the CPU tests run it at (``TINY``, ``SMALL``);
 * the traffic ``chipbench/workloads/<traffic>.json``: batch, sequence
   length, profiler settings and the driver that runs it (``driver``);
 * the driver ``chipbench/drivers/<driver>.py``: ``run(ctx) -> Outcome``;

@@ -16,9 +16,17 @@ class Readings:
     tokens: int  # their tokens
     window_s: float  # the traced window on the host's clock
     model: dict  # the configuration's "model" sizes
+    reference: str  # the configuration's model family: chipbench/reference/<reference>.py
     traffic: dict
     peaks: dict  # the chip's entry of chipbench.peaks.PEAKS
     counters: dict = field(default_factory=dict)  # CPU seconds over the window, by name
+
+    @property
+    def family(self):
+        """The module that counts this model's layers (``chipbench.flops``)."""
+        from chipbench.reference import layout
+
+        return layout.family(self.reference)
 
     @property
     def busy_s(self) -> float:
@@ -121,8 +129,8 @@ def roofline_share(m: Readings, scope: str) -> float | None:
     if t <= 0 or m.steps <= 0:
         return None
     B, S = int(m.traffic["batch"]), int(m.traffic["seq_len"])
-    ops = flops.scope_flops_per_step(m.model, scope, B, S) * m.steps
-    nbytes = flops.scope_bytes_per_step(m.model, scope, B, S) * m.steps
+    ops = flops.scope_flops_per_step(m.family, m.model, scope, B, S) * m.steps
+    nbytes = flops.scope_bytes_per_step(m.family, m.model, scope, B, S) * m.steps
     if ops <= 0:
         return None
     least = max(ops / m.peaks["flops"], nbytes / m.peaks["hbm_bytes_per_s"])

@@ -9,29 +9,24 @@ all the steps.
 
 from __future__ import annotations
 
-import importlib
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import layout
 from .common import adamw_update, learning_rate, leaf_norms
 
 
-def model_module(name: str):
-    """The reference model a configuration names (``reference`` in its file)."""
-    return importlib.import_module(f"{__package__}.{name}")
-
-
-def run_reference(model: str, cfg: dict, params0, batches, hyper: dict, *, lowp=None) -> dict:
+def run_reference(reference: str, cfg: dict, params0, batches, hyper: dict, *, lowp=None) -> dict:
     """-> {"loss": [...], "grad_norms": (n_leaves,), "change_norms": (n_leaves,)} as
     numpy, and the seconds it took to compile (``compile_s``) and to step (``steps_s``).
 
     ``params0`` is consumed (its buffers are donated).  ``hyper`` holds
     ``lr``, ``warmup`` and ``total_steps`` of the learning-rate schedule.
     """
-    loss_fn = model_module(model).loss
+    loss_fn = layout.family(reference).loss
     with jax.default_matmul_precision("highest"):
         t0 = time.perf_counter()
         grad_fn = jax.jit(jax.value_and_grad(lambda p, b: loss_fn(p, b, cfg, lowp)))

@@ -13,6 +13,9 @@ Departures of the configuration from its source are listed under ``assumed``
 in its file (no embedding, attention or residual multipliers, no logits
 scaling, untied embeddings).  Attention runs over blocks of queries, each
 checkpointed, so no (S, S) score matrix of the whole sequence is held.
+
+As a model family of the benchmark (``chipbench/reference/layout.py``) it
+lays out and counts layers of kind ``attn`` with an FFN of ``mlp``.
 """
 
 from __future__ import annotations
@@ -22,7 +25,71 @@ import math
 import jax
 import jax.numpy as jnp
 
+from . import layout
 from .common import F32, lm_loss, mm, rms_norm
+
+#: The cut of the harness's whole runs on the CPU (tests/chipbench), over the
+#: configuration's sizes.
+TINY = {"n_layers": 2, "d_model": 32, "n_heads": 2, "n_kv_heads": 1, "head_dim_": 16, "d_ff": 64, "vocab": 128,
+        "chunk": 8, "remat": "none"}
+#: The cut of the control's test on the CPU.
+SMALL = {"n_layers": 2, "d_model": 64, "n_heads": 4, "n_kv_heads": 1, "head_dim_": 16, "d_ff": 128, "vocab": 256}
+
+
+def _check(kind: str, ffn: str) -> None:
+    if (kind, ffn) != ("attn", "mlp"):
+        raise ValueError(f"no dense-decoder reference for a layer of kind {kind!r} with an FFN of {ffn!r}")
+
+
+def attention_shapes(cfg: dict) -> dict:
+    d, H = cfg["d_model"], cfg["n_heads"]
+    hd, hkv = cfg.get("head_dim_") or d // H, cfg["n_kv_heads"]
+    s = 1.0 / math.sqrt(d)
+    return {"wq": ((d, H, hd), s), "wk": ((d, hkv, hd), s), "wv": ((d, hkv, hd), s),
+            "wo": ((H, hd, d), 1.0 / math.sqrt(H * hd))}
+
+
+def mlp_shapes(d: int, f: int) -> dict:
+    s = 1.0 / math.sqrt(d)
+    return {"wi": ((d, f), s), "wo": ((f, d), 1.0 / math.sqrt(f)), "wg": ((d, f), s)}
+
+
+def layer_shapes(cfg: dict, kind: str, ffn: str) -> dict:
+    """(shape, std) of every leaf of one layer."""
+    _check(kind, ffn)
+    d = cfg["d_model"]
+    return {"norm1": layout.norm(d), "attn": attention_shapes(cfg), "norm2": layout.norm(d),
+            "mlp": mlp_shapes(d, cfg["d_ff"])}
+
+
+def _heads(cfg: dict) -> tuple[int, int, int]:
+    d, H = cfg["d_model"], cfg["n_heads"]
+    return H, cfg.get("n_kv_heads") or H, cfg.get("head_dim_") or d // H
+
+
+def attention_params(cfg: dict) -> int:
+    H, Hkv, hd = _heads(cfg)
+    return cfg["d_model"] * H * hd * 2 + cfg["d_model"] * Hkv * hd * 2
+
+
+def attention_flops_per_token(cfg: dict, seq_len: int) -> float:
+    """q.k and p.v over the causal half: on average (S+1)/2 keys a token."""
+    H, _, hd = _heads(cfg)
+    return 2 * 2 * H * hd * (seq_len + 1) / 2
+
+
+def layer_params(cfg: dict, kind: str, ffn: str) -> dict[str, int]:
+    """Weights of one layer, split by the scope that uses them."""
+    _check(kind, ffn)
+    return {"attention": attention_params(cfg), "mlp": 3 * cfg["d_model"] * cfg["d_ff"]}
+
+
+def forward_flops_per_token(cfg: dict, kind: str, ffn: str, seq_len: int) -> dict[str, float]:
+    """Forward operations per token of one layer, by scope: 2 per weight, and
+    attention's products of queries with keys and of weights with values."""
+    out = {k: 2.0 * v for k, v in layer_params(cfg, kind, ffn).items()}
+    out["attention"] += attention_flops_per_token(cfg, seq_len)
+    return out
 
 
 def rope(x, theta: float):
@@ -67,20 +134,18 @@ def mlp(p, x, lowp=None):
     return mm("bsf,fd->bsd", jax.nn.silu(g) * u, p["wo"], lowp=lowp)
 
 
+def block(p, x, cfg, lowp=None):
+    """One pre-norm block: x (B, S, d) -> (B, S, d)."""
+    a = x + attention(p["attn"], rms_norm(x, p["norm1"]["scale"]), cfg, lowp)
+    return a + mlp(p["mlp"], rms_norm(a, p["norm2"]["scale"]), lowp)
+
+
 def hidden(params, tokens, cfg, lowp=None):
     """Token ids (B, S) -> final-normed hidden states (B, S, d)."""
     x = jnp.take(params["embed"]["table"], tokens, axis=0)
-    if list(cfg["pattern"]) != ["attn"]:
-        raise ValueError("this reference is for a stack of attention blocks")
-    for u in range(cfg["n_layers"]):
-        p = jax.tree.map(lambda a: a[u], params["layers"]["scan"]["block0"])  # noqa: B023
-
-        @jax.checkpoint
-        def block(p, x):
-            a = x + attention(p["attn"], rms_norm(x, p["norm1"]["scale"]), cfg, lowp)
-            return a + mlp(p["mlp"], rms_norm(a, p["norm2"]["scale"]), lowp)
-
-        x = block(p, x)
+    for kind, ffn, p in layout.stack_layers(params["layers"], cfg):
+        _check(kind, ffn)
+        x = jax.checkpoint(lambda p, x: block(p, x, cfg, lowp))(p, x)
     return rms_norm(x, params["final_norm"]["scale"])
 
 

@@ -37,6 +37,9 @@ norm, and heads of d_model / n_heads.
 The sLSTM's backward pass goes through :func:`time_scan`, which checkpoints
 blocks of time steps, so the whole sequence's states are never held at once;
 the parallel mLSTM checkpoints each block of queries.
+
+As a model family of the benchmark (``chipbench/reference/layout.py``) it
+lays out and counts layers of kind ``slstm`` and ``mlstm``, with no FFN.
 """
 
 from __future__ import annotations
@@ -46,9 +49,62 @@ import math
 import jax
 import jax.numpy as jnp
 
+from . import layout
 from .common import F32, lm_loss, mm, rms_norm, rounded
 
 ICAP = 15.0
+
+#: The cut of the harness's whole runs on the CPU (tests/chipbench), over the
+#: configuration's sizes.
+TINY = {"n_layers": 4, "d_model": 32, "n_heads": 2, "n_kv_heads": 2, "vocab": 128, "chunk": 8, "remat": "none"}
+#: The cut of the control's test on the CPU.
+SMALL = {"n_layers": 4, "d_model": 64, "n_heads": 2, "vocab": 256, "chunk": 16}
+
+
+def _check(kind: str, ffn: str) -> None:
+    if kind not in MIXERS or ffn != "none":
+        raise ValueError(f"no xLSTM reference for a layer of kind {kind!r} with an FFN of {ffn!r}")
+
+
+def layer_shapes(cfg: dict, kind: str, ffn: str) -> dict:
+    """(shape, std) of every leaf of one layer."""
+    _check(kind, ffn)
+    d, H = cfg["d_model"], cfg["n_heads"]
+    hd, s = d // H, 1.0 / math.sqrt(d)
+    if kind == "mlstm":
+        mixer = {
+            "wq": ((d, H, hd), s), "wk": ((d, H, hd), s), "wv": ((d, H, hd), s),
+            "wi": ((d, H), s), "wf": ((d, H), s),
+            "wo_gate": ((d, d), s), "out_norm": layout.norm(d), "wo": ((d, d), s),
+        }
+    else:
+        mixer = {
+            "wx": ((d, 4, H, hd), s), "r": ((4, H, hd, hd), 1.0 / math.sqrt(hd)),
+            "b": ((4, H, hd), 0.1), "out_norm": layout.norm(d), "wo": ((d, d), s),
+        }
+    return {"norm1": layout.norm(d), kind: mixer}
+
+
+def layer_params(cfg: dict, kind: str, ffn: str) -> dict[str, int]:
+    """Weights of one layer, under the scope of its mixer."""
+    _check(kind, ffn)
+    d, H = cfg["d_model"], cfg["n_heads"]
+    hd = d // H
+    if kind == "mlstm":
+        return {"mlstm": 3 * d * H * hd + 2 * d * H + 2 * d * d}
+    return {"slstm": 4 * d * d + 4 * H * hd * hd + d * d}
+
+
+def forward_flops_per_token(cfg: dict, kind: str, ffn: str, seq_len: int) -> dict[str, float]:
+    """Forward operations per token of one layer: 2 per weight; the mLSTM's
+    recurrent form per head adds k v^T into the memory and q^T C out (2 hd^2
+    each); the sLSTM's recurrent matrix is among its weights."""
+    out = {k: 2.0 * v for k, v in layer_params(cfg, kind, ffn).items()}
+    if kind == "mlstm":
+        H = cfg["n_heads"]
+        hd = cfg["d_model"] // H
+        out["mlstm"] += 2 * 2 * H * hd * hd
+    return out
 
 
 def time_scan(step, carry, xs, block: int = 64):
@@ -167,17 +223,14 @@ MIXERS = {"slstm": slstm, "mlstm": mlstm}
 def hidden(params, tokens, cfg, lowp=None):
     """Token ids (B, S) -> final-normed hidden states (B, S, d)."""
     x = jnp.take(params["embed"]["table"], tokens, axis=0)
-    pattern = list(cfg["pattern"])
-    units = cfg["n_layers"] // len(pattern)
-    for u in range(units):
-        for j, kind in enumerate(pattern):
-            p = jax.tree.map(lambda a: a[u], params["layers"]["scan"][f"block{j}"])  # noqa: B023
+    for kind, ffn, p in layout.stack_layers(params["layers"], cfg):
+        _check(kind, ffn)
 
-            @jax.checkpoint
-            def block(p, x, kind=kind):
-                return x + MIXERS[kind](p[kind], rms_norm(x, p["norm1"]["scale"]), cfg, lowp)
+        @jax.checkpoint
+        def block(p, x, kind=kind):
+            return x + MIXERS[kind](p[kind], rms_norm(x, p["norm1"]["scale"]), cfg, lowp)
 
-            x = block(p, x)
+        x = block(p, x)
     return rms_norm(x, params["final_norm"]["scale"])
 
 

@@ -166,11 +166,21 @@ def scope_time(trace: Trace, scope: str) -> float:
 # -- reading a profiler dump ------------------------------------------------
 
 
+#: An instruction's name, where the instruction starts: at the start of a line.
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=", re.M)
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
 def _hlo_paths(hlo_text: str) -> dict[str, str]:
-    """instruction name -> op_name metadata, from HLO text."""
+    """instruction name -> op_name metadata, from HLO text.  An instruction
+    runs up to the start of the next: a Pallas kernel's custom call carries its
+    metadata as JSON with ``indent=0``, which breaks it over lines."""
     out = {}
-    for m in re.finditer(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*?op_name=\"([^\"]*)\"", hlo_text, re.M):
-        out.setdefault(m.group(1), m.group(2))
+    starts = list(_INSTRUCTION.finditer(hlo_text))
+    for m, end in zip(starts, [n.start() for n in starts[1:]] + [len(hlo_text)], strict=True):
+        op = _OP_NAME.search(hlo_text, m.end(), end)
+        if op:
+            out.setdefault(m.group(1), op.group(1))
     return out
 
 

@@ -3,7 +3,7 @@ below the configuration's (per-tensor-scaled float8 products, float8 e5m2
 cotangents), put in the program's place, against the float32 reference.
 
 On the chip ``chipbench/calibrate.py`` reads the same at each cell's own size;
-here the models are cut to a size a CPU test holds.
+here the models are cut to a size a CPU test holds, their family's ``SMALL``.
 """
 
 import os
@@ -23,21 +23,19 @@ from chipbench.reference.train import run_reference  # noqa: E402
 
 BENCH = harness.load_json(os.path.join(REPO, "BENCHMARK.json"))
 CELLS = [c["name"] for c in BENCH["workloads"]]
-SMALL = {"xlstm": {"n_layers": 4, "d_model": 64, "n_heads": 2, "vocab": 256, "chunk": 16},
-         "transformer": {"n_layers": 2, "d_model": 64, "n_heads": 4, "n_kv_heads": 1, "head_dim_": 16, "d_ff": 128,
-                         "vocab": 256}}
 
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_control_is_not_correct(cell):
     c, config, traffic = harness.resolve(BENCH, cell)
-    model = dict(config["model"], **SMALL[config["reference"]])
+    family = layout.family(config["reference"])
+    model = dict(config["model"], **family.SMALL)
     B, S, n = 2, 64, int(traffic["check_steps"])
     hyper = {"lr": float(traffic["lr"]), "warmup": int(traffic["lr_warmup"]), "total_steps": ENDLESS}
     key = seed_key(12345)
     make = batch_maker(B, S, model["vocab"])
     batches = [make(jax.random.fold_in(key, 1), k) for k in range(n)]
-    weights = lambda: layout.init_params(model, jax.random.fold_in(key, 0))  # noqa: E731
+    weights = lambda: layout.init_params(family, model, jax.random.fold_in(key, 0))  # noqa: E731
     ref = run_reference(config["reference"], model, weights(), batches, hyper)
     control = run_reference(config["reference"], model, weights(), batches, hyper,
                             lowp=jnp.dtype(config["dtypes"]["control"]))

@@ -14,6 +14,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 sys.path.insert(0, REPO)
 
 from chipbench import harness  # noqa: E402
+from chipbench.reference import layout  # noqa: E402
 
 BENCH = harness.load_json(os.path.join(REPO, "BENCHMARK.json"))
 CELLS = [c["name"] for c in BENCH["workloads"]]
@@ -53,6 +54,9 @@ def test_every_cell_resolves(cell):
     c, config, traffic = harness.resolve(BENCH, cell)
     assert os.path.isfile(harness.driver_path(traffic))
     assert os.path.isfile(os.path.join(harness.HERE, "reference", f"{config['reference']}.py"))
+    family = layout.family(config["reference"])
+    for name in ("loss", "layer_shapes", "layer_params", "forward_flops_per_token", "TINY", "SMALL"):
+        assert hasattr(family, name), name
     assert traffic["limits"] and set(traffic["limits"]) <= {"loss_gap", "grad_gap", "change_gap"}
     metrics = harness.per_layer_for(BENCH, c)
     assert metrics, "every cell reports a per-layer metric"
@@ -65,27 +69,24 @@ def test_every_cell_resolves(cell):
 
 # -- a whole run at a tiny size, on the CPU ------------------------------------
 
-TINY = {"n_layers": 4, "d_model": 32, "n_heads": 2, "n_kv_heads": 2, "vocab": 128, "chunk": 8, "remat": "none"}
-#: The cells' limits come from their own sizes on the chip.  At TINY widths the
-#: program's bfloat16 reads more (a sound run of the transformer on the CPU:
-#: loss_gap 0.0023, grad_gap 0.00084, change_gap 0.0015), and the faults read
-#: grad_gap and change_gap 1 (unchanged state), loss_gap 0.16, grad_gap 0.16
-#: and change_gap 0.20 (half batch).
+#: The cells' limits come from their own sizes on the chip.  At the family's
+#: ``TINY`` widths the program's bfloat16 reads more (a sound run of the
+#: transformer on the CPU: loss_gap 0.0023, grad_gap 0.00084, change_gap
+#: 0.0015; of the tests' dense-prefix family 0.0022, 0.0031, 0.0041), and
+#: the faults read grad_gap and change_gap 1 (unchanged state), loss_gap 0.16,
+#: grad_gap 0.16 and change_gap 0.20 (half batch).
 TINY_LIMITS = {"loss_gap": 0.02, "grad_gap": 0.5, "change_gap": 0.05}
 
 
 def tiny_run(cell: str, fault=None, monkeypatch=None) -> dict:
-    """Drive a whole run of ``cell`` on the CPU, its model cut to TINY widths,
-    with ``fault`` wrapped around the program's train step."""
+    """Drive a whole run of ``cell`` on the CPU, its model cut to its family's
+    ``TINY`` widths, with ``fault`` wrapped around the program's train step."""
     import jax
 
     import repro.launch.train as program_train
 
     c, config, traffic = harness.resolve(BENCH, cell)
-    model = dict(config["model"], **TINY)
-    if config["reference"] == "transformer":
-        model.update(n_layers=2, d_ff=64, head_dim_=16, n_kv_heads=1)
-    config = dict(config, model=model)
+    config = dict(config, model=dict(config["model"], **layout.family(config["reference"]).TINY))
     traffic = dict(traffic, batch=2, seq_len=32, limits=TINY_LIMITS)
     if fault is not None:
         real = program_train.make_train_step

@@ -29,7 +29,8 @@ def share(m):
 def readings(ops) -> Readings:
     tr = Trace(ops=ops, programs=[(0, 10 * MS, "jit_train_step", 0)],
                host=[(0, 10 * MS, "chipbench.window", "main")], window=(0.0, 10 * MS))
-    return Readings(tr, steps=1, tokens=4096, window_s=0.01, model={}, traffic={"batch": 1, "seq_len": 4096},
+    return Readings(tr, steps=1, tokens=4096, window_s=0.01, model={}, reference="transformer",
+                    traffic={"batch": 1, "seq_len": 4096},
                     peaks={"flops": 1e12, "hbm_bytes_per_s": 1e11})
 
 

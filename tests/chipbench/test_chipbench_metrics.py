@@ -12,7 +12,7 @@ sys.path.insert(0, REPO)
 
 from chipbench import harness  # noqa: E402
 from chipbench.readings import Readings, breakdown  # noqa: E402
-from chipbench.trace import Trace, in_scope, scope_time  # noqa: E402
+from chipbench.trace import Trace, _hlo_paths, in_scope, scope_time  # noqa: E402
 
 BENCH = harness.load_json(os.path.join(REPO, "BENCHMARK.json"))
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
@@ -41,8 +41,8 @@ def hand_trace() -> Readings:
     tr = Trace(ops=ops, programs=programs, host=host, window=(0.0, 10 * MS))
     traffic = {"batch": 2, "seq_len": 3}
     peaks = {"flops": 1e12, "hbm_bytes_per_s": 1e11}
-    return Readings(tr, steps=2, tokens=12, window_s=0.01, model=ATTN, traffic=traffic, peaks=peaks,
-                    counters={"agent": 0.001, "daemon": None})
+    return Readings(tr, steps=2, tokens=12, window_s=0.01, model=ATTN, reference="transformer", traffic=traffic,
+                    peaks=peaks, counters={"agent": 0.001, "daemon": None})
 
 
 def test_scope_matching():
@@ -50,6 +50,21 @@ def test_scope_matching():
     assert in_scope("a/jvp(slstm)/time_scan/while", "slstm/time_scan")
     assert not in_scope("a/attention_bias/x", "attention")
     assert not in_scope("a/slstm/in_proj/time_scan", "slstm/time_scan")
+
+
+def test_hlo_paths_read_a_custom_call_over_lines():
+    """The compiled HLO of a Pallas kernel with kernel metadata and a cost
+    estimate, for a TPU v5e (described, not attached; its Mosaic body cut): the
+    metadata JSON breaks the custom call over four lines."""
+    with open(os.path.join(DATA, "pallas-custom-call.hlo")) as f:
+        paths = _hlo_paths(f.read())
+    assert paths == {
+        "add.1": "jit(f)/attention/toy_kernel/add",
+        "add.0": "jit(f)/attention/toy_kernel/add",
+        "x.1": "x",
+        "toy_double.1": "jit(f)/attention/toy_kernel/toy_double/pallas_call",
+        "broadcast_add_fusion": "jit(f)/attention/toy_kernel/add",
+    }
 
 
 def test_readers_on_a_hand_built_trace():

@@ -7,6 +7,7 @@ loss is held to bfloat16 rounding.
 """
 
 import dataclasses
+import hashlib
 import os
 import sys
 
@@ -18,6 +19,7 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, REPO)
 
+from chipbench import harness  # noqa: E402
 from chipbench.reference import common, layout, transformer, xlstm  # noqa: E402
 from repro.configs import get_config  # noqa: E402
 from repro.models import Model  # noqa: E402
@@ -51,7 +53,7 @@ def close(a, b, tol=F32_TOL):
                          ids=["slstm", "mlstm_parallel", "mlstm_recurrent"])
 def test_xlstm_mixers_match_program_in_float32(kind, j, ref):
     cfg, cd = smoke("xlstm-125m")
-    p = block(layout.init_params(cd, jax.random.key(1)), j, kind)
+    p = block(layout.init_params(xlstm, cd, jax.random.key(1)), j, kind)
     x = jax.random.normal(jax.random.key(2), (2, 32, cfg.d_model))
     prog = getattr(p_xlstm, kind)
     with jax.default_matmul_precision("highest"):
@@ -67,7 +69,7 @@ def test_mlstm_parallel_form_is_the_recurrent_form():
     """Both forms of the reference, over several blocks of queries and with
     forget gates that decay far (the stabilisers of the two forms differ)."""
     cfg, cd = smoke("xlstm-125m")
-    p = block(layout.init_params(cd, jax.random.key(5)), 1, "mlstm")
+    p = block(layout.init_params(xlstm, cd, jax.random.key(5)), 1, "mlstm")
     p = dict(p, wf=p["wf"] * 8.0, wi=p["wi"] * 8.0)
     x = jax.random.normal(jax.random.key(6), (2, 64, cfg.d_model))
     with jax.default_matmul_precision("highest"):
@@ -82,7 +84,7 @@ def test_mlstm_parallel_form_is_the_recurrent_form():
 
 def test_attention_and_mlp_match_program_in_float32():
     cfg, cd = smoke("granite-3-8b")
-    params = layout.init_params(cd, jax.random.key(1))
+    params = layout.init_params(transformer, cd, jax.random.key(1))
     pa, pm = block(params, 0, "attn"), block(params, 0, "mlp")
     x = jax.random.normal(jax.random.key(2), (2, 32, cfg.d_model))
     pos = jnp.broadcast_to(jnp.arange(32), (2, 32))
@@ -97,7 +99,7 @@ def test_model_loss_matches_program(arch, ref):
     loss is an average over every token, so bf16 rounding of single
     activations (2**-9 relative) leaves it within 1e-3 relative."""
     cfg, cd = smoke(arch)
-    params = layout.init_params(cd, jax.random.key(3))
+    params = layout.init_params(ref, cd, jax.random.key(3))
     model = Model(cfg)
     want = jax.eval_shape(model.init, jax.random.key(0))
     assert jax.tree.structure(want) == jax.tree.structure(params)
@@ -144,3 +146,35 @@ def test_learning_rate_matches_program():
     prog = cosine_schedule(3e-3, warmup_steps=10, total_steps=100)
     for step in (0, 1, 5, 10, 40, 100, 200):
         assert abs(float(prog(step)) - common.learning_rate(step, 3e-3, 10, 100)) <= 1e-9
+
+
+#: sha256 (first 16 hex digits) of each leaf's float32 bytes of the granite
+#: cell's weights at its family's TINY widths, seed key 20261018, made as the
+#: driver makes them (one jitted call), as the parent of the family modules
+#: made them.  The benchmark's weights may not move under a change to how the
+#: layout is found.
+GRANITE_TINY_WEIGHTS = {
+    "['embed']['table']": "270bdafbff050edf",
+    "['final_norm']['scale']": "177489762c976603",
+    "['layers']['scan']['block0']['attn']['wk']": "1cfc1caabd181697",
+    "['layers']['scan']['block0']['attn']['wo']": "b28afc71ec251fd0",
+    "['layers']['scan']['block0']['attn']['wq']": "7cf21883b50499d1",
+    "['layers']['scan']['block0']['attn']['wv']": "4ac3a42cc450b242",
+    "['layers']['scan']['block0']['mlp']['wg']": "d19be147d43a956b",
+    "['layers']['scan']['block0']['mlp']['wi']": "f860938b68fd3f60",
+    "['layers']['scan']['block0']['mlp']['wo']": "691f8526e9f4c3bc",
+    "['layers']['scan']['block0']['norm1']['scale']": "f083f4263f2499a1",
+    "['layers']['scan']['block0']['norm2']['scale']": "1f670177f6997432",
+    "['lm_head']['w']": "f687413d40639c76",
+}
+
+
+def test_granite_weights_are_pinned():
+    bench = harness.load_json(os.path.join(REPO, "BENCHMARK.json"))
+    _, config, _ = harness.resolve(bench, "train.granite-3-8b.s4096")
+    fam = layout.family(config["reference"])
+    model = dict(config["model"], **fam.TINY)
+    params = jax.jit(lambda k: layout.init_params(fam, model, k))(jax.random.key(20261018))
+    got = {jax.tree_util.keystr(k): hashlib.sha256(np.asarray(v).tobytes()).hexdigest()[:16]
+           for k, v in jax.tree_util.tree_flatten_with_path(params)[0]}
+    assert got == GRANITE_TINY_WEIGHTS

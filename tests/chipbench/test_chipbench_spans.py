@@ -46,7 +46,7 @@ def span_trace(with_spans=True) -> Readings:
         for thread, events in (("main", main), ("repro-profilerd-agent", agent), ("repro-prof-watchdog", watchdog)):
             host += [(s * US, e * US, name, thread) for name, s, e in events]
     tr = Trace(ops=ops, programs=[], host=host, window=(0, 10000 * US))
-    return Readings(tr, steps=2, tokens=8, window_s=0.01, model={}, traffic={}, peaks={})
+    return Readings(tr, steps=2, tokens=8, window_s=0.01, model={}, reference="transformer", traffic={}, peaks={})
 
 
 def test_span_readers_on_a_hand_built_trace():
