@@ -34,12 +34,28 @@ class ModelConfig:
     pattern: tuple[str, ...] = ("attn",)  # layer-kind cycle
     rope_theta: float = 10000.0
     mrope: bool = False
+    # YaRN rotary scaling (DeepSeek-V2); factor 0 is plain RoPE
+    yarn_factor: float = 0.0
+    yarn_original_len: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+    # latent attention (layer kind "mla"): q/k heads are head_dim + qk_rope_dim
+    # wide, v heads v_head_dim; keys and values come from a latent of kv_lora_rank
+    kv_lora_rank: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
     # MoE
     n_experts: int = 0
     n_shared_experts: int = 0
     top_k: int = 0
     moe_d_ff: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # moe_capacity / moe_shard_map only: the dispatch that drops
+    norm_topk_prob: bool = True  # renormalise the top-k router weights to sum to 1
+    held_experts: tuple[int, int] | None = None  # [first, stop) of the routed experts this chip holds
+    aux_loss_alpha: float = 1e-2  # weight of the experts' balance term in the loss
+    seq_aux: bool = False  # balance term within each sequence (DeepSeek-V2), not over the batch
     first_dense: int = 0  # leading dense-FFN layers (DeepSeekMoE)
     dense_d_ff: int = 0
     moe_impl: str = "dense"  # dense (pjit dispatch) | shard_map (explicit EP a2a)
@@ -102,6 +118,8 @@ SUBQUADRATIC_FAMILIES = ("ssm", "hybrid")
 def shape_applicable(cfg: ModelConfig, shape: ShapeSpec) -> tuple[bool, str]:
     if shape.name == "long_500k" and cfg.family not in SUBQUADRATIC_FAMILIES:
         return False, "full-attention arch: 500k dense-KV decode is quadratic-cost (skip per assignment)"
+    if shape.kind == "decode" and "mla" in cfg.pattern:
+        return False, "latent attention has no decode cache yet"
     return True, ""
 
 
@@ -140,6 +158,7 @@ def _ensure_loaded() -> None:
         return
     from . import (  # noqa: F401
         deepseek_moe_16b,
+        deepseek_v2_lite,
         gemma_2b,
         granite_3_8b,
         llama3_2_3b,

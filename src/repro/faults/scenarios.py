@@ -234,12 +234,12 @@ class MoEImbalanceDriver(Driver):
 
         from repro.configs import get_config
         from repro.models.modules import init_params
-        from repro.models.moe import moe, moe_spec
+        from repro.models.moe import moe_capacity, moe_spec
 
         cfg = get_config("deepseek-moe-16b", smoke=True)
         self.cfg = cfg
         self.params = init_params(moe_spec(cfg), jax.random.key(0))
-        self._step_fn = jax.jit(lambda p, x: moe(p, x, cfg))
+        self._step_fn = jax.jit(lambda p, x: moe_capacity(p, x, cfg))
         self._collapse_v = (
             3.0 * self._rng.standard_normal(cfg.d_model).astype(np.float32)
         )

@@ -45,27 +45,32 @@ def flash_attention(
         return jnp.swapaxes(o, 1, 2)
 
 
-@functools.partial(jax.jit, static_argnames=("window", "interpret"))
+@functools.partial(jax.jit, static_argnames=("window", "scale", "interpret"))
 def splash_attention(
     q: jax.Array,  # (B, S, Hq, D)
     k: jax.Array,  # (B, T, Hkv, D)
-    v: jax.Array,
+    v: jax.Array,  # (B, T, Hkv, Dv)
     *,
     window: int | None = None,
+    scale: float | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Causal (optionally sliding-window) attention through JAX's splash
     kernel, differentiable: GQA without repeating K/V, fully-masked blocks
-    skipped in compute and DMA.  Scores are q.k / sqrt(D); softmax statistics
-    and accumulators are float32 inside the kernel."""
+    skipped in compute and DMA.  Scores are q.k times ``scale``, 1/sqrt(D) by
+    default; v heads may be narrower than q/k heads (latent attention), and
+    the output has theirs.  Softmax statistics and accumulators are float32
+    inside the kernel."""
     B, S, Hq, D = q.shape
-    T, Hkv = k.shape[1], k.shape[2]
-    if not splash_fits(S, T, Hq, Hkv, D):
-        raise ValueError(f"splash_attention does not take S={S}, T={T}, Hq={Hq}, Hkv={Hkv}, D={D}")
+    T, Hkv, Dv = k.shape[1], k.shape[2], v.shape[-1]
+    if not splash_fits(S, T, Hq, Hkv, D, Dv):
+        raise ValueError(f"splash_attention does not take S={S}, T={T}, Hq={Hq}, Hkv={Hkv}, D={D}, Dv={Dv}")
+    if scale is None:
+        scale = 1.0 / math.sqrt(D)
     with jax.named_scope("splash_attention"):
         kernel = splash_kernel(S, T, Hq, window, splash_blocks(S, T), interpret)
         # The kernel does not scale the scores: scale q once, in float32.
-        q = (q.astype(jnp.float32) * (1.0 / math.sqrt(D))).astype(q.dtype)
+        q = (q.astype(jnp.float32) * scale).astype(q.dtype)
         o = jax.vmap(kernel)(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2))
         return jnp.swapaxes(o, 1, 2)
 

@@ -42,9 +42,13 @@ def splash_blocks(S: int, T: int):
     )
 
 
-def splash_fits(S: int, T: int, Hq: int, Hkv: int, D: int) -> bool:
-    """Whether ``kernels.ops.splash_attention`` takes these shapes."""
-    return splash_blocks(S, T) is not None and D % 128 == 0 and Hq % Hkv == 0
+def splash_fits(S: int, T: int, Hq: int, Hkv: int, D: int, Dv: int | None = None) -> bool:
+    """Whether ``kernels.ops.splash_attention`` takes these shapes: q/k heads
+    of ``D`` (a multiple of 128, or of 64 from 128 up: latent attention's 192),
+    v heads of ``Dv`` (``D`` where not given, a multiple of 128)."""
+    Dv = D if Dv is None else Dv
+    return (splash_blocks(S, T) is not None and D >= 128 and D % 64 == 0 and Dv % 128 == 0
+            and Hq % Hkv == 0)
 
 
 def _one_line_json(**kwargs) -> bytes:
@@ -53,9 +57,9 @@ def _one_line_json(**kwargs) -> bytes:
 
 @functools.lru_cache(maxsize=64)
 def splash_kernel(S: int, T: int, Hq: int, window: int | None, blocks, interpret: bool = False):
-    """The splash kernel for one shape and tiling, on (Hq, S, D) queries and
-    (Hkv, T, D) keys and values.  Its block-sparse mask metadata is built here,
-    on the host, once per shape."""
+    """The splash kernel for one shape and tiling, on (Hq, S, D) queries, and
+    keys and values of (Hkv, T, D) and (Hkv, T, Dv).  Its block-sparse mask
+    metadata is built here, on the host, once per shape."""
     # JAX writes a kernel's metadata as JSON with ``indent=0``, which breaks
     # its custom-call over several lines of the compiled HLO text; readers
     # that map device ops to scope paths line by line (core/hlo_tree.py,

@@ -1,4 +1,5 @@
-"""Attention: GQA/MQA with RoPE/M-RoPE, qk-norm, sliding windows, KV cache.
+"""Attention: GQA/MQA with RoPE/M-RoPE, qk-norm, sliding windows, KV cache,
+and latent attention (MLA, :func:`mla`: keys and values from a shared latent).
 
 The training/prefill core takes one of three paths, chosen by
 :func:`attention_path` from the platform and the shapes:
@@ -25,7 +26,7 @@ import jax.numpy as jnp
 
 from repro.sharding.ctx import current_sharding_ctx, shard_activation
 
-from .modules import ArraySpec, apply_mrope, apply_rope, rms_norm, rms_norm_spec
+from .modules import ArraySpec, apply_mrope, apply_rope, rms_norm, rms_norm_spec, yarn_freqs, yarn_mscale
 
 NEG_INF = -2.0e38
 
@@ -69,13 +70,15 @@ def _mask(q_idx, k_idx, window: int | None):
     return m
 
 
-def _attend_full(q, k, v, cfg, *, q_offset: int = 0, window: int | None = None):
-    """q: (B,S,Hq,D); k,v: (B,T,Hkv,D). Materializes (B,Hkv,G,S,T)."""
+def _attend_full(q, k, v, cfg, *, q_offset: int = 0, window: int | None = None, scale: float | None = None):
+    """q, k: (B,S,Hq,D), (B,T,Hkv,D); v: (B,T,Hkv,Dv). Materializes (B,Hkv,G,S,T).
+    Scores are q.k times ``scale``, 1/sqrt(D) by default."""
     B, S, Hq, D = q.shape
     T, Hkv = k.shape[1], k.shape[2]
     G = Hq // Hkv
     qg = q.reshape(B, S, Hkv, G, D)
-    scale = 1.0 / math.sqrt(D)
+    if scale is None:
+        scale = 1.0 / math.sqrt(D)
     with jax.named_scope("scores"):
         s = jnp.einsum("bskgd,btkd->bkgst", qg, k).astype(jnp.float32) * scale
         mask = _mask(jnp.arange(S) + q_offset, jnp.arange(T), window)
@@ -83,10 +86,10 @@ def _attend_full(q, k, v, cfg, *, q_offset: int = 0, window: int | None = None):
         p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     with jax.named_scope("pv"):
         o = jnp.einsum("bkgst,btkd->bskgd", p, v)
-    return o.reshape(B, S, Hq, D)
+    return o.reshape(B, S, Hq, v.shape[-1])
 
 
-def _attend_chunked(q, k, v, cfg, *, window: int | None = None):
+def _attend_chunked(q, k, v, cfg, *, window: int | None = None, scale: float | None = None):
     """Online-softmax over query chunks: memory O(chunk * T), same math as
     the flash kernel (the Pallas kernel additionally tiles T into VMEM).
 
@@ -104,7 +107,9 @@ def _attend_chunked(q, k, v, cfg, *, window: int | None = None):
         q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
     qg = q.reshape(B, n_chunks, C, Hkv, G, D)
     qg = jnp.moveaxis(qg, 1, 0)  # (n_chunks, B, C, Hkv, G, D)
-    scale = 1.0 / math.sqrt(D)
+    Dv = v.shape[-1]
+    if scale is None:
+        scale = 1.0 / math.sqrt(D)
     k_idx = jnp.arange(T)
 
     # Sliding-window: each q-chunk only attends to the last `window` keys, so
@@ -123,7 +128,7 @@ def _attend_chunked(q, k, v, cfg, *, window: int | None = None):
         if use_strip:
             kstart = jnp.clip(i * C + C - Lk, 0, T - Lk)
             kc = jax.lax.dynamic_slice(k, (0, kstart, 0, 0), (k.shape[0], Lk, Hkv, D))
-            vc = jax.lax.dynamic_slice(v, (0, kstart, 0, 0), (v.shape[0], Lk, Hkv, D))
+            vc = jax.lax.dynamic_slice(v, (0, kstart, 0, 0), (v.shape[0], Lk, Hkv, Dv))
             kidx = kstart + jnp.arange(Lk)
         else:
             kc, vc, kidx = k, v, k_idx
@@ -142,17 +147,18 @@ def _attend_chunked(q, k, v, cfg, *, window: int | None = None):
     body = jax.checkpoint(body, policy=jax.checkpoint_policies.nothing_saveable, prevent_cse=False)
     with jax.named_scope("q_chunk_scan"):
         _, o = jax.lax.scan(body, None, (jnp.arange(n_chunks), qg))
-    o = jnp.moveaxis(o, 0, 1).reshape(B, n_chunks * C, Hkv, G, D)
+    o = jnp.moveaxis(o, 0, 1).reshape(B, n_chunks * C, Hkv, G, Dv)
     if pad:
         o = o[:, :S]
-    return o.reshape(B, S, Hq, D)
+    return o.reshape(B, S, Hq, Dv)
 
 
-def attention_path(cfg, S: int, T: int, Hq: int, Hkv: int, D: int, platform: str) -> str:
-    """The training/prefill attention core for these shapes: ``splash``,
-    ``chunked`` or ``full``.  ``auto`` takes the kernel on a TPU when its
-    tiles fit the shapes and no mesh of several devices is installed (a
-    Mosaic kernel is not partitioned for one)."""
+def attention_path(cfg, S: int, T: int, Hq: int, Hkv: int, D: int, platform: str, Dv: int | None = None) -> str:
+    """The training/prefill attention core for these shapes (q/k heads of
+    ``D``, v heads of ``Dv``, ``D`` where not given): ``splash``, ``chunked``
+    or ``full``.  ``auto`` takes the kernel on a TPU when its tiles fit the
+    shapes and no mesh of several devices is installed (a Mosaic kernel is
+    not partitioned for one)."""
     impl = cfg.attention_impl
     if impl in ("pallas", "pallas_interpret"):
         return "splash"
@@ -160,25 +166,99 @@ def attention_path(cfg, S: int, T: int, Hq: int, Hkv: int, D: int, platform: str
         from repro.kernels.splash_attention import splash_fits  # Pallas: imported only where it can run
 
         mesh, _ = current_sharding_ctx()
-        if splash_fits(S, T, Hq, Hkv, D) and (mesh is None or mesh.size == 1):
+        if splash_fits(S, T, Hq, Hkv, D, Dv) and (mesh is None or mesh.size == 1):
             return "splash"
     return "chunked" if S > cfg.chunk_threshold else "full"
+
+
+def _attend(q, k, v, cfg, *, window: int | None = None, scale: float | None = None):
+    """The attention core on the path :func:`attention_path` picks."""
+    _, S, Hq, D = q.shape
+    path = attention_path(cfg, S, k.shape[1], Hq, k.shape[2], D, jax.default_backend(), v.shape[-1])
+    if path == "splash":
+        from repro.kernels import ops as kops
+
+        return kops.splash_attention(q, k, v, window=window, scale=scale,
+                                     interpret=cfg.attention_impl == "pallas_interpret")
+    if path == "chunked":
+        return _attend_chunked(q, k, v, cfg, window=window, scale=scale)
+    return _attend_full(q, k, v, cfg, window=window, scale=scale)
 
 
 def attention(params, x, cfg, positions, *, window: int | None = None, scope: str = "attention"):
     """Training/prefill self-attention. x: (B,S,D) -> (B,S,D)."""
     with jax.named_scope(scope):
         q, k, v = _project_qkv(params, x, cfg, positions)
-        _, S, Hq, D = q.shape
-        path = attention_path(cfg, S, k.shape[1], Hq, k.shape[2], D, jax.default_backend())
-        if path == "splash":
-            from repro.kernels import ops as kops
+        o = _attend(q, k, v, cfg, window=window)
+        with jax.named_scope("out_proj"):
+            return jnp.einsum("bshk,hkd->bsd", o, params["wo"].astype(x.dtype))
 
-            o = kops.splash_attention(q, k, v, window=window, interpret=cfg.attention_impl == "pallas_interpret")
-        elif path == "chunked":
-            o = _attend_chunked(q, k, v, cfg, window=window)
-        else:
-            o = _attend_full(q, k, v, cfg, window=window)
+
+# ---------------------------------------------------------------------------
+# Latent attention (MLA, DeepSeek-V2): training/prefill in the expanded form
+# ---------------------------------------------------------------------------
+
+
+def mla_spec(cfg) -> dict:
+    """wq (d, H, dn + dr); the latent's down-projection w_dkv (d, r) and its
+    norm; the shared RoPE key w_kr (d, dr); per-head keys and values from the
+    latent, w_ukv (r, H, dn + dv); wo (H, dv, d).  dn is ``head_dim``, dr
+    ``qk_rope_dim``, dv ``v_head_dim`` and r ``kv_lora_rank``."""
+    d, H, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.head_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    return {
+        "wq": ArraySpec((d, H, dn + dr), ("embed", "q_heads", "head")),
+        "w_dkv": ArraySpec((d, r), ("embed", "kv_lora")),
+        "w_kr": ArraySpec((d, dr), ("embed", "head")),
+        "kv_norm": rms_norm_spec(r, "kv_lora"),
+        "w_ukv": ArraySpec((r, H, dn + dv), ("kv_lora", "q_heads", "head")),
+        "wo": ArraySpec((H, dv, d), ("q_heads", "head", "embed")),
+    }
+
+
+def mla_scale(cfg) -> float:
+    """The score scale: 1/sqrt(dn + dr), times YaRN's temperature squared."""
+    m = yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim)
+    return m * m / math.sqrt(cfg.head_dim + cfg.qk_rope_dim)
+
+
+def _mla_rope(x, positions, cfg):
+    """RoPE on the last axis of x (..., S, H, dr), with YaRN's frequencies and
+    its cos/sin factor where the configuration stretches the context."""
+    if not cfg.yarn_factor:
+        return apply_rope(x, positions, cfg.rope_theta)
+    freqs = yarn_freqs(x.shape[-1], cfg.rope_theta, cfg.yarn_factor, cfg.yarn_original_len, cfg.yarn_beta_fast,
+                       cfg.yarn_beta_slow)
+    out = apply_rope(x, positions, cfg.rope_theta, freqs=freqs)
+    f = yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale) / yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim)
+    return out if f == 1.0 else (out.astype(jnp.float32) * f).astype(x.dtype)
+
+
+def mla(params, x, cfg, positions, *, scope: str = "attention"):
+    """Latent attention, training/prefill. x: (B,S,d) -> (B,S,d).
+
+    c = RMSNorm(x W_dkv) is the latent every head reads; k_R = RoPE(x W_kr)
+    the RoPE key all heads share.  Head h has [k_C,h ; v_h] = c W_ukv,h and
+    q_h = [q_C,h ; RoPE(q_R,h)] = x W_q,h, attends with k_h = [k_C,h ; k_R],
+    and the heads' outputs go through W_o.  Keys and values are expanded per
+    head, so the core is multi-head attention with q/k heads of dn + dr and
+    v heads of dv, on the path :func:`attention_path` picks."""
+    dn, dr = cfg.head_dim, cfg.qk_rope_dim
+    with jax.named_scope(scope):
+        with jax.named_scope("q_proj"):
+            q = jnp.einsum("bsd,dhk->bshk", x, params["wq"].astype(x.dtype))
+        with jax.named_scope("kv_down"):
+            c = jnp.einsum("bsd,dr->bsr", x, params["w_dkv"].astype(x.dtype))
+            k_r = jnp.einsum("bsd,dk->bsk", x, params["w_kr"].astype(x.dtype))
+        c = rms_norm(params["kv_norm"], c, scope="kv_norm")
+        with jax.named_scope("kv_up"):
+            kv = jnp.einsum("bsr,rhk->bshk", c, params["w_ukv"].astype(x.dtype))
+        k_c, v = kv[..., :dn], kv[..., dn:]
+        with jax.named_scope("rope"):
+            q = jnp.concatenate([q[..., :dn], _mla_rope(q[..., dn:], positions, cfg)], axis=-1)
+            k_r = _mla_rope(k_r[:, :, None, :], positions, cfg)
+            k = jnp.concatenate([k_c, jnp.broadcast_to(k_r, (*k_c.shape[:3], dr))], axis=-1)
+        o = _attend(q, k, v, cfg, scale=mla_scale(cfg))
         with jax.named_scope("out_proj"):
             return jnp.einsum("bshk,hkd->bsd", o, params["wo"].astype(x.dtype))
 

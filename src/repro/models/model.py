@@ -104,9 +104,9 @@ class Model:
             positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), x.shape[:2])
             if cfg.mrope:
                 positions = jnp.broadcast_to(positions[..., None], (*x.shape[:2], 3))
-        x, lb = tfm.stack_apply(params["layers"], x, cfg, positions)
+        x, stats = tfm.stack_apply(params["layers"], x, cfg, positions)
         x = rms_norm(params["final_norm"], x, scope="final_norm")
-        return x, lb
+        return x, stats
 
     def logits_fn(self, params, x):
         cfg = self.cfg
@@ -120,14 +120,23 @@ class Model:
 
     def forward(self, params, batch) -> tuple[jax.Array, jax.Array]:
         """-> (logits (B,S,V), moe load-balance loss)."""
+        logits, stats = self._forward(params, batch)
+        return logits, stats["lb_loss"]
+
+    def _forward(self, params, batch) -> tuple[jax.Array, dict]:
         with jax.named_scope("model"):
-            x, lb = self._trunk(params, batch)
-            return self.logits_fn(params, x), lb
+            x, stats = self._trunk(params, batch)
+            return self.logits_fn(params, x), stats
 
     def loss(self, params, batch) -> tuple[jax.Array, dict]:
-        """Causal-LM cross entropy (+ z-loss + MoE aux)."""
+        """Causal-LM cross entropy (+ z-loss + MoE aux).  With experts the aux
+        also carries the step's routing counters: ``moe_held_tokens``, the
+        slots routed to held experts summed over the expert layers, and
+        ``moe_load_max``, the largest of the layers' held-expert load over its
+        mean."""
         with jax.named_scope("loss"):
-            logits, lb = self.forward(params, batch)
+            logits, stats = self._forward(params, batch)
+            lb = stats["lb_loss"]
             labels = batch["labels"]
             mask = batch.get("loss_mask")
             lsm = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
@@ -137,8 +146,9 @@ class Model:
             denom = jnp.maximum(mask.sum(), 1.0)
             ce = (nll * mask).sum() / denom
             zl = 1e-4 * ((jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1) ** 2) * mask).sum() / denom
-            total = ce + zl + 1e-2 * lb
-            return total, {"ce": ce, "z_loss": zl, "lb_loss": lb}
+            total = ce + zl + self.cfg.aux_loss_alpha * lb
+            counters = {k: v for k, v in stats.items() if k.startswith("moe_")}
+            return total, {"ce": ce, "z_loss": zl, "lb_loss": lb, **counters}
 
     # -- decode -----------------------------------------------------------------------
 

@@ -151,9 +151,34 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0) -> jax.Array:
-    """x: (..., S, H, D); positions: broadcastable to (..., S)."""
-    freqs = rope_freqs(x.shape[-1], theta)
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's temperature for a context stretched ``factor`` times: 0.1 * mscale * ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freqs(head_dim: int, theta: float, factor: float, original_len: int, beta_fast: float,
+               beta_slow: float) -> jax.Array:
+    """YaRN's rotary frequencies (``DeepseekV2YarnRotaryEmbedding``): the
+    frequencies that turn more than ``beta_fast`` times over ``original_len``
+    positions are kept, those that turn fewer than ``beta_slow`` times are
+    divided by ``factor``, and a linear ramp over the dimensions blends them
+    in between."""
+
+    def dim_of(rotations: float) -> float:
+        return head_dim * math.log(original_len / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(dim_of(beta_fast)), 0)
+    high = min(math.ceil(dim_of(beta_slow)), head_dim - 1)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    kept = rope_freqs(head_dim, theta)
+    return kept / factor * ramp + kept * (1.0 - ramp)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0, freqs: jax.Array | None = None) -> jax.Array:
+    """x: (..., S, H, D); positions: broadcastable to (..., S).  ``freqs``
+    (D/2,) replaces the plain frequencies theta^(-2i/D)."""
+    if freqs is None:
+        freqs = rope_freqs(x.shape[-1], theta)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, D/2)
     angles = angles[..., None, :]  # head axis
     cos, sin = jnp.cos(angles), jnp.sin(angles)

@@ -21,9 +21,9 @@ from . import attention as attn_mod
 from . import rglru as rec_mod
 from . import xlstm as xlstm_mod
 from .mlp import mlp, mlp_spec
-from .moe import moe, moe_spec
+from .moe import moe, moe_capacity, moe_spec, routing_counters
 from .modules import rms_norm, rms_norm_spec, stack_specs
-from repro.sharding.ctx import shard_activation
+from repro.sharding.ctx import current_sharding_ctx, shard_activation
 
 
 # ---------------------------------------------------------------------------
@@ -50,6 +50,8 @@ def block_spec(cfg, kind: str, ffn: str) -> dict:
     spec: dict[str, Any] = {"norm1": rms_norm_spec(d)}
     if kind == "attn":
         spec["attn"] = attn_mod.attention_spec(cfg)
+    elif kind == "mla":
+        spec["attn"] = attn_mod.mla_spec(cfg)
     elif kind == "rec":
         spec["rec"] = rec_mod.recurrent_block_spec(cfg)
     elif kind == "slstm":
@@ -70,13 +72,30 @@ def block_spec(cfg, kind: str, ffn: str) -> dict:
     return spec
 
 
+def zero_stats(cfg) -> dict:
+    """The statistics a layer adds to the step's: the MoE balance loss, and
+    with experts the routing counters (``stack_apply``)."""
+    stats = {"lb_loss": jnp.zeros((), jnp.float32)}
+    if cfg.n_experts:
+        stats["moe_held_tokens"] = jnp.zeros((), jnp.float32)
+        stats["moe_load_max"] = jnp.zeros((), jnp.float32)
+    return stats
+
+
+def _add_stats(acc: dict, stats: dict) -> dict:
+    """Sums over layers, but the largest load."""
+    return {k: jnp.maximum(v, stats[k]) if k == "moe_load_max" else v + stats[k] for k, v in acc.items()}
+
+
 def block_apply(params, x, cfg, kind: str, ffn: str, positions, *, scope: str):
-    """One residual block (training/prefill). Returns (x, lb_loss)."""
-    lb = jnp.zeros((), jnp.float32)
+    """One residual block (training/prefill). Returns (x, stats) (:func:`zero_stats`)."""
+    stats = zero_stats(cfg)
     with jax.named_scope(scope):
         h = rms_norm(params["norm1"], x, scope="pre_norm")
         if kind == "attn":
             y = attn_mod.attention(params["attn"], h, cfg, positions, window=cfg.window)
+        elif kind == "mla":
+            y = attn_mod.mla(params["attn"], h, cfg, positions)
         elif kind == "rec":
             y = rec_mod.recurrent_block(params["rec"], h, cfg)
         elif kind == "slstm":
@@ -94,24 +113,52 @@ def block_apply(params, x, cfg, kind: str, ffn: str, positions, *, scope: str):
             h2 = rms_norm(params["norm2"], x, scope="pre_moe_norm")
             y2, aux = _apply_moe(params["moe"], h2, cfg)
             x = x + y2
-            lb = aux["lb_loss"]
+            stats = {"lb_loss": aux["lb_loss"], "moe_held_tokens": aux["held_tokens"],
+                     "moe_load_max": aux["load_max"]}
         x = shard_activation(x, ("batch", None, None))
-    return x, lb
+    return x, stats
 
 
 def _apply_moe(params, h, cfg):
-    """Dense-dispatch (pjit) or explicit shard_map EP, per cfg.moe_impl."""
+    """The expert layer: explicit shard_map EP (static capacity) where
+    cfg.moe_impl asks for it and the mesh allows it, else :func:`_moe_for_mesh`."""
     if cfg.moe_impl == "shard_map":
-        from repro.sharding.ctx import current_sharding_ctx
-
         mesh, rules = current_sharding_ctx()
         if mesh is not None and "model" in mesh.shape and cfg.n_experts % mesh.shape["model"] == 0:
             from .moe_shard_map import moe_shard_map
 
+            _refuse_held_share(cfg)
             batch = rules.get("batch", ("data",))
             data_axes = (batch,) if isinstance(batch, str) else tuple(batch)
-            return moe_shard_map(params, h, cfg, mesh=mesh, data_axes=data_axes)
-    return moe(params, h, cfg)
+            y, aux = moe_shard_map(params, h, cfg, mesh=mesh, data_axes=data_axes)
+            return y, _with_counters(aux, h, cfg)
+    return _moe_for_mesh(params, h, cfg)
+
+
+def _moe_for_mesh(params, h, cfg):
+    """The dropless expert layer on one device.  On a mesh of several, the
+    static-capacity dispatch, whose (E, C, D) expert buffer GSPMD shards over
+    the experts (``expert_buf``) and whose slots over the batch: no dropless
+    exchange across devices is built yet, so there the slots past capacity
+    are dropped."""
+    mesh, _ = current_sharding_ctx()
+    if mesh is None or mesh.size == 1:
+        return moe(params, h, cfg)
+    _refuse_held_share(cfg)
+    y, aux = moe_capacity(params, h, cfg)
+    return y, _with_counters(aux, h, cfg)
+
+
+def _refuse_held_share(cfg) -> None:
+    if cfg.held_experts:
+        raise ValueError("a layer that holds a share of the experts (held_experts) runs dropless on one "
+                         "device; the dispatches across devices hold every expert")
+
+
+def _with_counters(aux, h, cfg) -> dict:
+    """A static-capacity dispatch's aux with the dropless one's routing counters."""
+    slots = h.shape[0] * h.shape[1] * cfg.top_k
+    return dict(aux, **routing_counters(aux["expert_frac"] * slots, cfg))
 
 
 def block_decode(params, x, state, pos, cfg, kind: str, ffn: str, *, scope: str):
@@ -134,12 +181,14 @@ def block_decode(params, x, state, pos, cfg, kind: str, ffn: str, *, scope: str)
             x = x + mlp(params["mlp"], h2, act=cfg.act)
         elif ffn == "moe":
             h2 = rms_norm(params["norm2"], x, scope="pre_moe_norm")
-            y2, _ = moe(params["moe"], h2, cfg)
+            y2, _ = _moe_for_mesh(params["moe"], h2, cfg)
             x = x + y2
     return x, new_state
 
 
 def layer_state_init(cfg, kind: str, batch: int, max_len: int, abstract: bool = False):
+    if kind == "mla":
+        raise NotImplementedError("latent attention (mla) has no decode state: its latent cache is not built yet")
     if kind == "attn":
         fn = attn_mod.abstract_kv_cache if abstract else attn_mod.init_kv_cache
         return fn(cfg, batch, max_len)
@@ -202,27 +251,28 @@ def stack_spec(cfg) -> dict:
 
 
 def stack_apply(params, x, cfg, positions):
-    """Full layer stack forward. Returns (x, total_lb_loss)."""
+    """Full layer stack forward. Returns (x, stats): the layers' balance loss
+    summed, and with experts the routing counters (:func:`zero_stats`)."""
     lay = StackLayout(cfg)
-    lb_total = jnp.zeros((), jnp.float32)
+    total = zero_stats(cfg)
     for i in lay.prefix:
-        x, lb = block_apply(
+        x, stats = block_apply(
             params["prefix"][f"layer{i}"], x, cfg, layer_kind(cfg, i), _ffn_kind(cfg, i),
             positions, scope=f"layer{i}",
         )
-        lb_total += lb
+        total = _add_stats(total, stats)
 
     if lay.n_units:
         def unit_body(carry, unit_params):
-            h, lb_acc = carry
+            h, acc = carry
             for j, kind in enumerate(lay.unit_kinds):
                 with jax.named_scope(f"unit_block{j}_{kind}"):
-                    h, lb = block_apply(
+                    h, stats = block_apply(
                         unit_params[f"block{j}"], h, cfg, kind,
                         _ffn_kind(cfg, cfg.first_dense + j), positions, scope=f"block{j}",
                     )
-                    lb_acc += lb
-            return (h, lb_acc), None
+                    acc = _add_stats(acc, stats)
+            return (h, acc), None
 
         body = unit_body
         if cfg.remat != "none":
@@ -238,15 +288,15 @@ def stack_apply(params, x, cfg, positions):
             params["scan"],
         )
         with jax.named_scope("layers"):
-            (x, lb_total), _ = jax.lax.scan(body, (x, lb_total), scan_params)
+            (x, total), _ = jax.lax.scan(body, (x, total), scan_params)
 
     for i in lay.remainder:
-        x, lb = block_apply(
+        x, stats = block_apply(
             params["remainder"][f"layer{i}"], x, cfg, layer_kind(cfg, i), _ffn_kind(cfg, i),
             positions, scope=f"layer{i}",
         )
-        lb_total += lb
-    return x, lb_total
+        total = _add_stats(total, stats)
+    return x, total
 
 
 def stack_decode(params, x, states, pos, cfg):

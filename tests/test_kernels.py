@@ -159,6 +159,56 @@ def test_splash_matches_model_full_path(Hq, Hkv, window):
         assert float(jnp.max(jnp.abs(got.astype(jnp.float32) - causal))) > 0.1
 
 
+def test_splash_with_narrower_values_matches_model_full_path():
+    """Latent attention's core: q/k heads of 192, v heads of 128, a score
+    scale other than 1/sqrt(D).  Output and the q, k and v gradients against
+    the model's XLA path in float32, as above."""
+    from repro.configs import get_config
+    from repro.models.attention import _attend_full
+
+    cfg = get_config("deepseek-v2-lite", smoke=True)
+    B, S, H, D, Dv, scale = 1, 256, 2, 192, 128, 0.1147
+    ks = jax.random.split(jax.random.key(15), 4)
+    q = jax.random.normal(ks[0], (B, S, H, D), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (B, S, H, D), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (B, S, H, Dv), jnp.bfloat16)
+    do = jax.random.normal(ks[3], (B, S, H, Dv), jnp.float32)
+
+    def kernel_loss(q, k, v):
+        o = ops.splash_attention(q, k, v, scale=scale, interpret=True)
+        return jnp.sum(o.astype(jnp.float32) * do), o
+
+    def ref_loss(q, k, v):
+        o = _attend_full(q, k, v, cfg, scale=scale)
+        return jnp.sum(o * do), o
+
+    (_, got), g_got = jax.value_and_grad(kernel_loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    f32 = [a.astype(jnp.float32) for a in (q, k, v)]
+    (_, want), g_want = jax.value_and_grad(ref_loss, argnums=(0, 1, 2), has_aux=True)(*f32)
+    assert got.shape == (B, S, H, Dv)
+    allclose(got, want, jnp.bfloat16)
+    for a, b in zip(g_got, g_want):
+        assert a.shape == b.shape
+        rel = float(jnp.linalg.norm(a.astype(jnp.float32) - b) / jnp.linalg.norm(b))
+        assert rel < 1e-2, rel
+    # the scale is applied: the default 1/sqrt(192) is far off
+    default = _attend_full(*f32, cfg)
+    assert float(jnp.max(jnp.abs(got.astype(jnp.float32) - default))) > 0.1
+
+
+def test_latent_attention_takes_the_kernel_on_a_tpu():
+    """MLA's 16 heads of 192 (q/k) and 128 (v) fit the kernel on a TPU; the
+    CPU keeps the XLA path; heads of 64 stay off the kernel."""
+    from repro.configs import get_config
+    from repro.models.attention import attention_path
+
+    cfg = get_config("deepseek-v2-lite")
+    assert attention_path(cfg, 4096, 4096, 16, 16, 192, "tpu", 128) == "splash"
+    assert attention_path(cfg, 4096, 4096, 16, 16, 192, "cpu", 128) == "full"
+    assert attention_path(cfg, 4096, 4096, 16, 16, 64, "tpu", 64) == "full"
+    assert attention_path(cfg, 4096, 4096, 16, 16, 192, "tpu", 96) == "full"
+
+
 def test_model_attention_through_splash_matches_xla():
     """``attention()`` under ``pallas_interpret`` runs the kernel and agrees
     with the same layer under ``xla``."""

@@ -1,5 +1,6 @@
-"""shard_map EP MoE vs dense-dispatch MoE: numeric equivalence on a real
-multi-device mesh (subprocess: device-count forcing must precede jax init)."""
+"""shard_map EP MoE vs its unsharded form, the static-capacity dispatch
+(``moe_capacity``): numeric equivalence on a real multi-device mesh
+(subprocess: device-count forcing must precede jax init)."""
 
 import os
 import subprocess
@@ -16,7 +17,7 @@ import jax, jax.numpy as jnp, numpy as np
 from dataclasses import replace
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config
-from repro.models.moe import moe, moe_spec
+from repro.models.moe import moe_capacity, moe_spec
 from repro.models.moe_shard_map import moe_shard_map
 from repro.models.modules import init_params
 from repro.sharding.ctx import sharding_ctx
@@ -31,7 +32,7 @@ B, S = 4, 16
 x = jax.random.normal(jax.random.key(1), (B, S, cfg.d_model), jnp.float32)
 
 with mesh, sharding_ctx(mesh, {"batch": ("data",), "expert_buf": "model"}):
-    y_dense, aux_d = jax.jit(lambda p, x: moe(p, x, cfg))(params, x)
+    y_dense, aux_d = jax.jit(lambda p, x: moe_capacity(p, x, cfg))(params, x)
     y_ep, aux_e = jax.jit(
         lambda p, x: moe_shard_map(p, x, cfg, mesh=mesh, data_axes=("data",))
     )(params, x)

@@ -42,7 +42,8 @@ def make_batch(cfg, key, batch=B, seq=S):
 
 def test_all_assigned_archs_registered():
     assert set(ARCHS) <= set(list_archs())
-    assert len(list_archs()) == 10
+    # deepseek-v2-lite has no decode path yet (latent cache): tests/test_mla_moe.py runs it
+    assert set(list_archs()) - set(ARCHS) == {"deepseek-v2-lite"}
 
 
 @pytest.mark.parametrize("arch", ARCHS)

@@ -7,6 +7,8 @@ inside a fixture, never at import, so every test worker collects the same
 tests and only the one given this file loads the TPU library.
 """
 
+import hashlib
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -113,3 +115,54 @@ def test_xlstm_serve_step_compiles_and_is_costed(one_chip):
     # Every weight but the embedding table takes one multiply-add per token.
     want = 2 * batch * (model.n_params - cfg.vocab * cfg.d_model)
     assert tree.total("flops") == pytest.approx(want, rel=0.05)
+
+
+def test_latent_attention_splash_and_its_gradient_compile(one_chip):
+    """DeepSeek-V2-Lite's MLA core: 16 heads, q/k of 192 (the kernel's blocks
+    take the whole 192 lanes), v of 128, its YaRN-scaled score scale."""
+    q = _spec((1, 4096, 16, 192), jnp.bfloat16, one_chip)
+    v = _spec((1, 4096, 16, 128), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(ops.splash_attention(q, k, v, scale=0.1147).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, v).compile().as_text()
+    assert len([ln for ln in text.splitlines() if "tpu_custom_call" in ln]) == 2
+
+
+def test_held_experts_layer_and_its_gradient_compile(one_chip, monkeypatch):
+    """The dropless expert layer at the cell's sizes: 4 x 4096 tokens, top 6
+    of 64 experts, 8 held, their products in the megablox kernel as a TPU
+    runs them."""
+    import dataclasses
+
+    from repro.models.modules import abstract_params
+    from repro.models.moe import moe, moe_spec
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # grouped_swiglu reads the platform
+
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite"), held_experts=(0, 8))
+    params = jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip), abstract_params(moe_spec(cfg)))
+    x = _spec((4, 4096, cfg.d_model), jnp.bfloat16, one_chip)
+
+    def loss(p, x):
+        y, aux = moe(p, x, cfg)
+        return jnp.sum(y.astype(jnp.float32)) + aux["lb_loss"]
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x).compile()
+    # three products forward, and each one's two backward kernels (gmm, tgmm)
+    assert compiled.as_text().count("tpu_custom_call") >= 9
+    assert compiled.memory_analysis().temp_size_in_bytes < 8e9
+
+
+def test_granite_train_step_lowers_for_the_chip_as_before(one_chip, monkeypatch):
+    """Granite's train step on the chip's path (the splash kernel's Mosaic
+    bodies included), lowered for a described v5e, source locations left out,
+    hashes as the parent of the MLA and held-expert layers lowered it."""
+    from test_mla_moe import granite_step_text
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # attention_path reads the platform
+    text = granite_step_text(one_chip)
+    assert text.count("tpu_custom_call") >= 3
+    want = "2b9c642e5f8eb75925a72f80f5e659516ebc4c68fd69fd51a8f4d14a8bb69183"
+    assert hashlib.sha256(text.encode()).hexdigest() == want
